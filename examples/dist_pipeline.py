@@ -10,7 +10,9 @@ re-issued on the surviving local worker, every result exactly once.
 
 The driver logic lives in ``repro.net.demo`` (module-level so the
 ``multiprocessing`` spawn child can import it); this file is the runnable
-front door.
+front door. The worker process runs JAX on the CPU (set in
+``repro.net.demo.run_child``), so on a TPU host the chip stays with the
+process that runs this file.
 
 Run:  PYTHONPATH=src python examples/dist_pipeline.py
 """

@@ -5,24 +5,3 @@ with CAF" (Hiesgen, Charousset, Schmidt; Agere/LNCS 2017), adapted to
 JAX/TPU. See DESIGN.md.
 """
 __version__ = "0.1.0"
-
-# jax < 0.5 compat: expose the stable jax.shard_map spelling, and
-# normalize Compiled.cost_analysis() to the modern single-dict return
-# (older versions hand back a one-element list per executable).
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _jax.shard_map = _shard_map
-
-    _orig_cost_analysis = _jax.stages.Compiled.cost_analysis
-
-    def _cost_analysis(self):
-        out = _orig_cost_analysis(self)
-        if isinstance(out, list):
-            out = out[0] if out else {}
-        return out
-
-    _jax.stages.Compiled.cost_analysis = _cost_analysis
-del _jax

@@ -1,7 +1,8 @@
 """Architecture registry: ``--arch <id>`` → exact published config.
 
 Every assigned architecture has ``configs/<id>.py`` with ``config()``
-(full shape, dry-run only) and ``smoke_config()`` (reduced, CPU-testable).
+(the published widths and depth: what ``--full`` runs on a chip, and what
+the dry-run plans for a pod) and ``smoke_config()`` (reduced, CPU-testable).
 """
 from . import (dbrx, llama3_8b, mamba2_130m, nemotron4_340b, phi35_moe,
                qwen2_vl, qwen3_1p7b, qwen15_32b, recurrentgemma_9b,

@@ -63,8 +63,8 @@ def _device_of(arr) -> Optional[jax.Device]:
         devs = arr.devices()
         if len(devs) == 1:
             return next(iter(devs))
-    except Exception:  # pragma: no cover - tracers / older jax
-        pass  # lint: device probe; tracers and older jax lack .devices()
+    except Exception:  # pragma: no cover - tracers
+        pass  # lint: device probe; tracers have no concrete devices()
     dev = getattr(arr, "device", None)
     return dev if isinstance(dev, jax.Device) else None
 
@@ -361,10 +361,7 @@ class DeviceRef:
         """True once the producing computation has completed on device."""
         if self._state != "live":
             return True
-        try:
-            return bool(self._array.is_ready())
-        except AttributeError:  # pragma: no cover - older jax
-            return True
+        return bool(self._array.is_ready())
 
     # -- access rights ------------------------------------------------------
     def restrict(self, access: str) -> "DeviceRef":

@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-import repro  # noqa: F401  — installs the jax.shard_map compat alias
 from repro.core.memref import DeviceRef, as_device_array
 
 __all__ = ["compressed_psum", "tree_psum_with_error_feedback",

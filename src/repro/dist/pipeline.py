@@ -37,30 +37,32 @@ def _positions_for(cfg, b: int, s: int):
     return jnp.broadcast_to(base, (3, b, s)) if cfg.m_rope else base
 
 
-def _stage_fn(model, chunk_units, first: bool, last: bool,
-              embed, final_norm, head):
-    """A pure ``(chunk_params, x) → x`` function for one stage.
+def _stage_fn(model, chunk_units, first: bool, last: bool):
+    """A pure ``(stage_params, x) → x`` function for one stage.
 
     The first stage embeds tokens; the last applies the final norm and LM
     head. Middle stages are pure residual-stream transforms, so only the
-    [B, S, D] activation crosses actor boundaries."""
+    [B, S, D] activation crosses actor boundaries. ``stage_params`` holds
+    the stage's layers under ``"units"`` plus whichever of ``embed``,
+    ``final_norm`` and ``head`` the stage needs."""
     cfg = model.cfg
 
-    def stage(chunk_params, x):
+    def stage(stage_params, x):
         if first:
             tokens = x
             b, s = tokens.shape
-            x = embed_inputs({"embed": embed}, cfg, tokens, None)
+            x = embed_inputs(stage_params, cfg, tokens, None)
         else:
             b, s = x.shape[0], x.shape[1]
         positions = _positions_for(cfg, b, s)
         aux = jnp.zeros((), jnp.float32)
-        for unit, lp in zip(chunk_units, chunk_params):
+        for unit, lp in zip(chunk_units, stage_params["units"]):
             x, aux = _apply_unit(lp, cfg, unit, x, positions, aux,
                                  model.attn_impl)
         if last:
-            x = apply_norm(final_norm, x, cfg.norm)
-            h = embed.T if cfg.tie_embeddings else head
+            x = apply_norm(stage_params["final_norm"], x, cfg.norm)
+            h = (stage_params["embed"].T if cfg.tie_embeddings
+                 else stage_params["head"])
             return x @ h.astype(x.dtype)
         return x
 
@@ -70,6 +72,11 @@ def _stage_fn(model, chunk_units, first: bool, last: bool,
 def make_layer_stage_actors(system: ActorSystem, model, params,
                             n_stages: int) -> List[ActorRef]:
     """Split the layer stack into ``n_stages`` contiguous stage actors.
+
+    Stage ``i`` lives on the ``i``-th device the system's
+    :class:`~repro.core.manager.DeviceManager` lists (wrapping round when
+    there are fewer devices than stages): its parameters are placed there,
+    its program runs there, and each incoming activation is moved there.
 
     The staged forward reproduces ``model.forward`` exactly (same per-layer
     ops in the same order); only the logits (not the MoE aux loss) leave
@@ -87,25 +94,30 @@ def make_layer_stage_actors(system: ActorSystem, model, params,
         raise ValueError(f"n_stages={n_stages} not in [1, {n_layers}]")
     sizes = [n_layers // n_stages + (1 if i < n_layers % n_stages else 0)
              for i in range(n_stages)]
-    head = params.get("head")
+    devices = system.opencl_manager().devices()
     stages, lo = [], 0
     for si, sz in enumerate(sizes):
         chunk = units[lo:lo + sz]
-        last = si == n_stages - 1
+        first, last = si == 0, si == n_stages - 1
         lo += sz
-        fn = _stage_fn(model, [u for u, _ in chunk],
-                       first=(si == 0), last=last,
-                       embed=params["embed"],
-                       final_norm=params["final_norm"], head=head)
-        jitted = jax.jit(fn)
-        chunk_params = [p for _, p in chunk]
+        stage_params = {"units": [p for _, p in chunk]}
+        if first or (last and cfg.tie_embeddings):
+            stage_params["embed"] = params["embed"]
+        if last:
+            stage_params["final_norm"] = params["final_norm"]
+            if not cfg.tie_embeddings:
+                stage_params["head"] = params["head"]
+        device = devices[si % len(devices)].jax_device
+        stage_params = jax.device_put(stage_params, device)
+        jitted = jax.jit(_stage_fn(model, [u for u, _ in chunk],
+                                   first=first, last=last))
 
         # stages speak DeviceRef natively: inputs are unwrapped (host
         # microbatches are transferred once, by the first stage) and the
         # [B, S, D] activation crosses actor boundaries as a ref — the
         # composed chain releases it once the next stage has consumed it
-        def _stage(x, _f=jitted, _p=chunk_params, _last=last):
-            y = _f(_p, as_device_array(x))
+        def _stage(x, _f=jitted, _p=stage_params, _d=device, _last=last):
+            y = _f(_p, jax.device_put(as_device_array(x), _d))
             return y if _last else DeviceRef(y)
 
         stages.append(system.spawn(_stage))

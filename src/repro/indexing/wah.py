@@ -35,12 +35,14 @@ _FILL_FLAG = jnp.uint32(1) << 31
 _COUNT_MASK = (1 << 30) - 1
 
 
-@functools.partial(jax.jit, static_argnames=("cardinality",))
-def build_wah_index(values: jax.Array, cardinality: int):
+@functools.partial(jax.jit, static_argnames=("cardinality", "impl"))
+def build_wah_index(values: jax.Array, cardinality: int, impl: str = "auto"):
     """Build a WAH bitmap index of ``values`` (uint32 < cardinality).
 
     Returns ``(index_words, n_words, starts, counts)``: the compacted word
-    stream, its logical length, and the per-value lookup table.
+    stream, its logical length, and the per-value lookup table. ``impl``
+    picks the sort/interleave/compaction kernels as in
+    :mod:`repro.kernels.ops` (``"ref"``: the pure-jnp oracles).
     """
     n = values.shape[0]
     values = values.astype(jnp.uint32)
@@ -48,7 +50,7 @@ def build_wah_index(values: jax.Array, cardinality: int):
 
     # (1)+(2): encode with position, stable sort by value → positions stay
     # ascending within each value, hence chunk ids are ascending.
-    v_sorted, pos_sorted = ops.radix_sort(values, pos)
+    v_sorted, pos_sorted = ops.radix_sort(values, pos, impl=impl)
     v_sorted = v_sorted.astype(jnp.int32)
 
     # (3): 31-bit chunk literals by segmented OR (distinct bits → sum).
@@ -77,8 +79,8 @@ def build_wah_index(values: jax.Array, cardinality: int):
                       _FILL_FLAG | gap.astype(jnp.uint32), 0).astype(jnp.uint32)
 
     # (5): fuseFillsLiterals — interleave then compact (paper Listing 5).
-    fused = ops.wah_interleave(fills, literals)
-    index_words, n_words = ops.stream_compact(fused)
+    fused = ops.wah_interleave(fills, literals, impl=impl)
+    index_words, n_words = ops.stream_compact(fused, impl=impl)
 
     # (6): lookup table — words contributed per segment, summed per value.
     words_per_seg = jnp.where(seg_valid, (gap > 0).astype(jnp.int32) + 1, 0)

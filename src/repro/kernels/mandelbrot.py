@@ -14,38 +14,41 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["pallas_mandelbrot"]
 
 
-def _mandelbrot_kernel(o_ref, *, max_iter: int, re_min: float, im_min: float,
-                       re_step: float, im_step: float, bh: int, bw: int,
-                       row_offset: int):
+def _mandelbrot_kernel(o_ref, zr_ref, zi_ref, *, max_iter: int,
+                       re_min: float, im_min: float, re_step: float,
+                       im_step: float, bh: int, bw: int, row_offset: int):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    rows = jax.lax.broadcasted_iota(jnp.float32, (bh, bw), 0)
-    cols = jax.lax.broadcasted_iota(jnp.float32, (bh, bw), 1)
+    # the TPU's iota is integer-only; pixel indices are exact in f32
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bh, bw), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bh, bw), 1)
     # global pixel coordinates of this tile (NDRange offsets, paper §3.4)
-    y = rows + (i * bh + row_offset)
-    x = cols + j * bw
+    y = (rows + (i * bh + row_offset)).astype(jnp.float32)
+    x = (cols + j * bw).astype(jnp.float32)
     cr = re_min + x * re_step
     ci = im_min + y * im_step
 
-    def body(_, carry):
-        zr, zi, count = carry
+    # z and the count live in VMEM refs rather than in the loop carry: the
+    # TPU lowering cannot carry a splat-initialised tile through the loop.
+    zr_ref[...] = jnp.zeros((bh, bw), jnp.float32)
+    zi_ref[...] = jnp.zeros((bh, bw), jnp.float32)
+    o_ref[...] = jnp.zeros((bh, bw), jnp.int32)
+
+    @pl.loop(0, max_iter)
+    def _(_):
+        zr, zi = zr_ref[...], zi_ref[...]
         zr2, zi2 = zr * zr, zi * zi
         alive = (zr2 + zi2) <= 4.0
         nzr = zr2 - zi2 + cr
         nzi = 2.0 * zr * zi + ci
-        zr = jnp.where(alive, nzr, zr)
-        zi = jnp.where(alive, nzi, zi)
-        return zr, zi, count + alive.astype(jnp.int32)
-
-    zr = jnp.zeros((bh, bw), jnp.float32)
-    zi = jnp.zeros((bh, bw), jnp.float32)
-    cnt = jnp.zeros((bh, bw), jnp.int32)
-    _, _, cnt = jax.lax.fori_loop(0, max_iter, body, (zr, zi, cnt))
-    o_ref[...] = cnt
+        zr_ref[...] = jnp.where(alive, nzr, zr)
+        zi_ref[...] = jnp.where(alive, nzi, zi)
+        o_ref[...] += alive.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -74,5 +77,6 @@ def pallas_mandelbrot(*, height: int, width: int, max_iter: int,
         grid=grid,
         out_specs=pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((height, width), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((bh, bw), jnp.float32)] * 2,
         interpret=interpret,
     )()

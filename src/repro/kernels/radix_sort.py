@@ -5,10 +5,13 @@ Per digit pass the GPU version builds per-work-group histograms and ranks
 with warp ballots. The TPU kernel computes, per block and entirely on the
 MXU/VPU:
 
-    onehot[src, bin] = (digit[src] == bin)            # (bs × nbins)
-    hist[bin]        = ones(1,bs) @ onehot            # digit histogram
-    before           = strict_lower_tri(bs) @ onehot  # prefix per bin
-    rank[src]        = Σ_bin before[src,bin] * onehot[src,bin]
+    onehotT[bin, src] = (digit[src] == bin)              # (nbins × bs)
+    hist[bin]         = ones(1,bs) @ onehotTᵀ            # digit histogram
+    beforeT           = onehotT @ strict_upper_tri(bs)   # prefix per bin
+    rank[src]         = Σ_bin beforeT[bin,src] * onehotT[bin,src]
+
+A grid step takes up to eight such blocks, one per sublane row of its
+tile, since the TPU lowering needs 8-row tiles (or the whole array).
 
 The wrapper (``ops.radix_sort``) turns (hist, rank) into global
 destination indices with two tiny cumsums and applies the permutation with
@@ -26,26 +29,35 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tiling import as_i32, block_rows, pad_rows
+
 __all__ = ["pallas_radix_pass"]
 
+_NT = (((1,), (1,)), ((), ()))        # contract both operands' last dims
 
-def _radix_pass_kernel(x_ref, hist_ref, rank_ref, *, bs: int, nbins: int,
-                       shift: int):
-    x = x_ref[...].astype(jnp.uint32)                          # (1, bs)
-    digit = ((x >> jnp.uint32(shift)) & jnp.uint32(nbins - 1)).astype(jnp.int32)
-    bins = jax.lax.broadcasted_iota(jnp.int32, (bs, nbins), 1)
-    onehot = (digit.reshape(bs, 1) == bins).astype(jnp.float32)  # (bs, nbins)
 
-    ones_row = jnp.ones((1, bs), jnp.float32)
-    hist = jnp.dot(ones_row, onehot, preferred_element_type=jnp.float32)
-    hist_ref[...] = hist.astype(jnp.int32)                     # (1, nbins)
-
+def _radix_pass_kernel(x_ref, hist_ref, rank_ref, *, rows: int, bs: int,
+                       nbins: int, shift: int):
+    # Each row of the tile is one ``bs``-element block. The one-hot is
+    # built transposed, (nbins, bs), so that the digits stay on the lanes
+    # and no lane/sublane relayout is needed. 0/1 values are exact in bf16.
+    bins = jax.lax.broadcasted_iota(jnp.int32, (nbins, bs), 0)
     r = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
-    tril = (c < r).astype(jnp.float32)                         # strictly lower
-    before = jnp.dot(tril, onehot, preferred_element_type=jnp.float32)
-    rank = jnp.sum(before * onehot, axis=1)                    # (bs,)
-    rank_ref[...] = rank.astype(jnp.int32).reshape(1, bs)
+    upper = (r < c).astype(jnp.bfloat16)                       # strictly upper
+    ones_row = jnp.ones((1, bs), jnp.bfloat16)
+    for i in range(rows):
+        x = x_ref[i:i + 1, :]                                  # (1, bs) int32
+        digit = jax.lax.shift_right_logical(x, jnp.int32(shift)) & (nbins - 1)
+        onehot_t = (digit == bins).astype(jnp.bfloat16)        # (nbins, bs)
+        hist = jax.lax.dot_general(ones_row, onehot_t, _NT,
+                                   preferred_element_type=jnp.float32)
+        hist_ref[i:i + 1, :] = hist.astype(jnp.int32)          # (1, nbins)
+        before_t = jnp.dot(onehot_t, upper,
+                           preferred_element_type=jnp.float32)  # (nbins, bs)
+        rank = jnp.sum(before_t * onehot_t.astype(jnp.float32), axis=0,
+                       keepdims=True)                          # (1, bs)
+        rank_ref[i:i + 1, :] = rank.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "bits", "shift", "interpret"))
@@ -56,18 +68,21 @@ def pallas_radix_pass(x: jax.Array, *, bs: int = 256, bits: int = 8,
     (n,) = x.shape
     assert n % bs == 0, (n, bs)
     nb, nbins = n // bs, 1 << bits
-    xb = x.reshape(nb, bs)
-    return pl.pallas_call(
-        functools.partial(_radix_pass_kernel, bs=bs, nbins=nbins, shift=shift),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, bs), lambda b: (b, 0))],
+    rows, padded = block_rows(nb)
+    xb = pad_rows(as_i32(x).reshape(nb, bs), padded)
+    hist, rank = pl.pallas_call(
+        functools.partial(_radix_pass_kernel, rows=rows, bs=bs, nbins=nbins,
+                          shift=shift),
+        grid=(padded // rows,),
+        in_specs=[pl.BlockSpec((rows, bs), lambda b: (b, 0))],
         out_specs=[
-            pl.BlockSpec((1, nbins), lambda b: (b, 0)),
-            pl.BlockSpec((1, bs), lambda b: (b, 0)),
+            pl.BlockSpec((rows, nbins), lambda b: (b, 0)),
+            pl.BlockSpec((rows, bs), lambda b: (b, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, nbins), jnp.int32),
-            jax.ShapeDtypeStruct((nb, bs), jnp.int32),
+            jax.ShapeDtypeStruct((padded, nbins), jnp.int32),
+            jax.ShapeDtypeStruct((padded, bs), jnp.int32),
         ],
         interpret=interpret,
     )(xb)
+    return hist[:nb], rank[:nb]

@@ -26,11 +26,22 @@ __all__ = ["run_worker", "main"]
 def run_worker(addr: Tuple[str, int], name: str, *,
                compress: bool = False,
                max_workers: int = 8,
-               timeout: Optional[float] = None) -> None:
+               timeout: Optional[float] = None,
+               platforms: Optional[str] = None) -> None:
     """Connect to the driver at ``addr`` and serve until it disconnects.
 
     Blocks in ``NodeRuntime.join()``; on return the local actor system is
-    shut down. Runs in a fresh process, so imports stay inside."""
+    shut down. Runs in a fresh process, so imports stay inside.
+
+    ``platforms`` (e.g. ``"cpu"``) pins this process's JAX backends before
+    its first JAX call. A worker spawned on its parent's host takes
+    ``"cpu"``: a TPU belongs to one process, so once the parent holds the
+    chip a worker's first JAX call fails on libtpu's lock (or, where
+    ``JAX_PLATFORMS`` is unset, quietly falls back to the CPU). ``None``
+    (a worker on a host of its own) lets JAX pick the host's devices."""
+    if platforms is not None:
+        import jax
+        jax.config.update("jax_platforms", platforms)
     from repro.core import ActorSystem
     from repro.net import NodeRuntime
     from repro.serve.mesh import local_replica_stats

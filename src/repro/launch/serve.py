@@ -16,7 +16,7 @@ from __future__ import annotations
 import argparse
 import time
 
-__all__ = ["main", "check_cache_capacity"]
+__all__ = ["main", "run", "check_cache_capacity"]
 
 
 def check_cache_capacity(steps: int, capacity: int) -> int:
@@ -37,7 +37,8 @@ def check_cache_capacity(steps: int, capacity: int) -> int:
     return capacity
 
 
-def _run_engine(args, cfg, model, params, serve_step) -> int:
+def _run_engine(args, cfg, model, params, serve_step) -> dict:
+    import jax
     import jax.numpy as jnp
     import numpy as np
     from repro.core import ActorSystem, memory_stats
@@ -46,9 +47,17 @@ def _run_engine(args, cfg, model, params, serve_step) -> int:
     capacity = args.steps + 1
     check_cache_capacity(args.steps, capacity)
 
-    def step_fn(cache, tokens):
+    # The weights are an argument of the jitted step, not a closure: a
+    # closed-over array becomes a constant of the compiled program, which
+    # at full width copies gigabytes into every compile (and cache entry).
+    # Hence jit_step=False below: the engine must not re-jit this closure.
+    @jax.jit
+    def batched_step(params, cache, tokens):
         nxt, _, cache = serve_step(params, cache, tokens[:, None])
         return nxt[:, 0], cache
+
+    def step_fn(cache, tokens):
+        return batched_step(params, cache, tokens)
 
     def init_fn(prompt):
         return model.init_cache(1, capacity), int(prompt)
@@ -58,7 +67,6 @@ def _run_engine(args, cfg, model, params, serve_step) -> int:
     # axis 0 and batch on axis 1). Leaves with no batch axis — the scalar
     # decode position — are batch-uniform and shared, which gang
     # scheduling keeps aligned.
-    import jax
     s1 = jax.tree_util.tree_leaves(
         jax.eval_shape(lambda: model.init_cache(1, capacity)))
     s2 = jax.tree_util.tree_leaves(
@@ -80,7 +88,8 @@ def _run_engine(args, cfg, model, params, serve_step) -> int:
     with ActorSystem(name="serve") as system:
         engine = ServeEngine(system, step_fn, init_fn,
                              n_workers=args.workers, max_batch=args.batch,
-                             allow_join=False, combine=combine, split=split)
+                             allow_join=False, combine=combine, split=split,
+                             jit_step=False)
         t0 = time.perf_counter()
         with engine:
             futs = [engine.submit(0, max_new_tokens=args.steps)
@@ -99,10 +108,10 @@ def _run_engine(args, cfg, model, params, serve_step) -> int:
     print("memref:", {k: v for k, v in memory_stats().items()
                       if k in ("transfers", "readbacks", "live_refs")})
     print("sample:", np.asarray(results[0].tokens)[:16].tolist())
-    return 0
+    return {"results": results, "seconds": dt, "stats": stats}
 
 
-def _run_paged(args, cfg) -> int:
+def _run_paged(args, cfg) -> dict:
     """Paged-mode demo: disaggregated prefill/decode over a PagePool.
 
     Runs a single-layer greedy attention decoder at the config's model
@@ -202,10 +211,10 @@ def _run_paged(args, cfg) -> int:
     print("memref:", {k: v for k, v in memory_stats().items()
                       if k in ("transfers", "readbacks", "live_refs")})
     print("sample:", np.asarray(results[0].tokens)[:16].tolist())
-    return 0
+    return {"results": results, "seconds": dt, "stats": stats}
 
 
-def _run_sync(args, cfg, model, params, serve_step) -> int:
+def _run_sync(args, cfg, model, params, serve_step) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
@@ -230,11 +239,17 @@ def _run_sync(args, cfg, model, params, serve_step) -> int:
     dt = time.perf_counter() - t0
     print(f"{cfg.name}: {args.steps} steps × {args.batch} requests "
           f"in {dt:.2f}s ({args.steps * args.batch / dt:,.0f} tok/s)")
-    print("sample:", np.concatenate(outs, axis=1)[0, :16].tolist())
-    return 0
+    tokens = np.concatenate(outs, axis=1)
+    print("sample:", tokens[0, :16].tolist())
+    return {"tokens": tokens, "seconds": dt}
 
 
-def main(argv=None) -> int:
+def run(argv=None) -> dict:
+    """Parse ``argv``, serve, and return what was served.
+
+    The dict always holds ``cfg``; engine and paged modes add the request
+    ``results`` and engine ``stats``, sync mode the ``[batch, steps]``
+    ``tokens``; every mode but paged adds ``model`` and ``params``."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--requests", type=int, default=32,
@@ -260,23 +275,30 @@ def main(argv=None) -> int:
     import jax
     from repro import configs
     from repro.dist import step as step_mod
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import Model
 
+    enable_compile_cache()
     cfg = (configs.get_config if args.full else configs.get_smoke_config)(
         args.arch)
     if args.paged:
-        return _run_paged(args, cfg)
+        return {"cfg": cfg, **_run_paged(args, cfg)}
     model = Model(cfg)
     params = model.init(jax.random.key(0))
+    out = {"cfg": cfg, "model": model, "params": params}
 
     if args.sync or cfg.family == "encdec":
         serve_step = jax.jit(step_mod.build_serve_step(model),
                              donate_argnums=(1,))
-        return _run_sync(args, cfg, model, params, serve_step)
-    # engine mode: the worker jits the batched step itself (and retries
-    # must be able to replay a cache, so no donation here)
+        return {**out, **_run_sync(args, cfg, model, params, serve_step)}
+    # engine mode: retries must be able to replay a cache, so no donation
     serve_step = step_mod.build_serve_step(model)
-    return _run_engine(args, cfg, model, params, serve_step)
+    return {**out, **_run_engine(args, cfg, model, params, serve_step)}
+
+
+def main(argv=None) -> int:
+    run(argv)
+    return 0
 
 
 if __name__ == "__main__":

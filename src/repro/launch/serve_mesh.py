@@ -19,6 +19,11 @@ snapshots it into ``BENCH_PR8.json``.
 Everything here is module-level so both sides of the spawn can import it
 (the worker needs :func:`toy_engine` importable to build the shipped
 :class:`~repro.serve.mesh.ReplicaSpec`).
+
+The worker processes run JAX on the CPU (``run_worker(platforms="cpu")``,
+set before their first JAX call); only the parent process may take an
+accelerator. A TPU belongs to one process: without the setting, a worker
+on a TPU host whose parent holds the chip fails at its first JAX call.
 """
 from __future__ import annotations
 
@@ -112,7 +117,7 @@ def run_demo(workers: int = 2, *, rps: float = 40.0, duration_s: float = 6.0,
         for i in range(workers):
             name = f"worker{i}"
             p = ctx.Process(target=run_worker, args=(node.address, name),
-                            daemon=True)
+                            kwargs={"platforms": "cpu"}, daemon=True)
             p.start()
             children[name] = p
         for name in children:

@@ -1,8 +1,8 @@
 """Production training launcher.
 
-On real hardware this runs under the pod mesh with the per-arch plan from
-``dryrun_lib.plan_for``; on this container it runs any arch's smoke config
-end-to-end (the 512-device path is exercised by ``dryrun.py``).
+Runs any arch's smoke config end to end on the CPU or one chip; ``--full``
+takes the published config. The pod-scale plan from ``dryrun_lib.plan_for``
+is exercised by ``dryrun.py``.
 
     PYTHONPATH=src python -m repro.launch.train --arch llama3-8b \
         --steps 100 --batch 8 --seq 64 [--smoke/--full] [--ckpt DIR]
@@ -25,7 +25,9 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--full", action="store_true",
-                    help="use the full published config (pod-scale only)")
+                    help="use the full published config instead of the "
+                         "smoke one; its train state (params, grads, AdamW "
+                         "moments) must fit the devices' memory")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
@@ -34,9 +36,11 @@ def main(argv=None) -> int:
     from repro.checkpoint import checkpoint as ckpt
     from repro.data import Prefetcher, SyntheticLM
     from repro.dist import step as step_mod
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import Model
     from repro.optim import AdamWConfig, schedule
 
+    enable_compile_cache()
     cfg = (configs.get_config if args.full else configs.get_smoke_config)(
         args.arch)
     model = Model(cfg)

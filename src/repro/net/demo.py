@@ -19,6 +19,12 @@ What it demonstrates (the PR's acceptance criteria):
    process mid-run delivers a :class:`~repro.core.errors.DownMessage` to
    local monitors, and the chunks in flight on the dead node are re-issued
    on the surviving local worker with every result counted exactly once.
+
+The worker process runs JAX on the CPU (``jax_platforms="cpu"``, set in
+:func:`run_child` before its first JAX call); only the parent process may
+take an accelerator. A TPU belongs to one process: without the setting,
+the worker on a TPU host fails at its first JAX call once the parent
+holds the chip.
 """
 from __future__ import annotations
 
@@ -58,7 +64,11 @@ def chunk_work(i: int):
 
 def run_child(addr: Tuple[str, int], name: str, compress: bool) -> None:
     """Worker-process entry: join the cluster, publish the stage and the
-    chunk worker, serve until the driver goes away (or is killed)."""
+    chunk worker, serve until the driver goes away (or is killed).
+
+    JAX runs on the CPU here, leaving any accelerator to the parent."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     from repro.core import ActorSystem
     from repro.net import NodeRuntime
 

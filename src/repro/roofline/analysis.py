@@ -192,8 +192,6 @@ def analyze(compiled, *, arch: str, shape: str, mesh_name: str, chips: int,
     numbers are preserved in ``memory_per_device['xla_cost_*']``."""
     from . import hlo_stats
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
     hlo = compiled.as_text()
     stats = hlo_stats.analyze_module(hlo, ici_bw=ICI_BW, seq_dims=seq_dims)
     flops = stats.flops

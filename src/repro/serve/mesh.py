@@ -330,12 +330,12 @@ class MeshRouter:
     def _prefix_key(self, prompt: Any) -> Optional[str]:
         if not self.route_by_prefix:
             return None
+        if isinstance(prompt, (str, bytes)):
+            return repr(prompt[:self.prefix_tokens])
         try:
-            if isinstance(prompt, (str, bytes)):
-                return repr(prompt[:self.prefix_tokens])
             return repr(list(prompt[:self.prefix_tokens]))
-        except Exception:
-            return None
+        except TypeError:   # a scalar prompt (one token) is its own prefix
+            return repr(prompt)
 
     # -- dispatch / replay -------------------------------------------------
     def _pick_locked(self, key: Optional[str],
