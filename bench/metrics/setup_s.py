@@ -1,0 +1,4 @@
+"""setup_s: Set-up seconds: process start to the first timed request (host clock)."""
+
+def read(run):
+    return run.setup_s

@@ -1,0 +1,6 @@
+"""wah.pallas_ms_per_call: Device time of the Pallas kernels per ask in the traced window (ms)."""
+from bench import readers
+
+
+def read(run):
+    return readers.kernel_ms_per_call(run, pallas=True)
