@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import itertools
 import threading
+import time
 import traceback
 import weakref
 from collections import deque
@@ -29,6 +30,7 @@ from concurrent.futures import TimeoutError as FuturesTimeout
 from typing import Any, Callable, Optional, Tuple
 
 from ..analysis.runtime import make_lock
+from ..trace import span
 from .errors import ActorFailed, DownMessage, ExitMessage, MailboxClosed
 
 __all__ = ["Actor", "ActorRef", "ActorSystem", "Message"]
@@ -62,13 +64,15 @@ def _safe_set_exception(fut: Optional[Future], exc: BaseException) -> None:
 
 
 class Message:
-    __slots__ = ("payload", "reply_to", "sender")
+    __slots__ = ("payload", "reply_to", "sender", "t_enqueue")
 
     def __init__(self, payload: Tuple[Any, ...], reply_to: Optional[Future] = None,
                  sender: Optional["ActorRef"] = None):
         self.payload = payload
         self.reply_to = reply_to
         self.sender = sender
+        #: ``time.monotonic()`` when the message entered a mailbox
+        self.t_enqueue = 0.0
 
 
 class ActorRef:
@@ -376,6 +380,7 @@ class ActorSystem:
 
     # -- scheduling internals ----------------------------------------------
     def _enqueue(self, actor_id: int, msg: Message) -> None:
+        msg.t_enqueue = time.monotonic()
         st = self._actors.get(actor_id)
         delivered = False
         if st is not None:
@@ -433,7 +438,9 @@ class ActorSystem:
                     isinstance(msg.payload[0], ExitMessage) and not actor.trap_exit:
                 self._terminate(actor_id, msg.payload[0].reason)
                 return
-            result = actor.receive(*msg.payload)
+            with span("actor.receive", actor=actor_id,
+                      queued_ms=(time.monotonic() - msg.t_enqueue) * 1e3):
+                result = actor.receive(*msg.payload)
         except Exception as exc:  # abnormal termination → fault propagation
             _safe_set_exception(msg.reply_to, exc)
             traceback.clear_frames(exc.__traceback__) if exc.__traceback__ else None

@@ -58,6 +58,7 @@ from repro.core.errors import DeadlineExceeded
 from repro.core.memref import DeviceRef, tree_release, tree_wrap
 from repro.core.placement import service as placement_service
 from repro.core.scheduler import ChunkScheduler
+from repro.trace import span
 
 from .batcher import Batcher
 from .kvpool import (PagePool, PageTable, make_paged_decode_worker,
@@ -107,24 +108,29 @@ def make_decode_worker(step_fn: Callable, *, combine: Optional[Callable] = None,
             raise ValueError(f"decode worker got unknown message {tag!r}")
         nreq = len(caches)
         nleaves = len(caches[0])
-        cols = [combine([caches[b][i].array for b in range(nreq)], i)
-                for i in range(nleaves)]
-        cache = jax.tree_util.tree_unflatten(treedef, cols)
-        new_tokens, new_cache = fn(cache, jnp.asarray(tokens))
+        with span("serve.combine"):
+            cols = [combine([caches[b][i].array for b in range(nreq)], i)
+                    for i in range(nleaves)]
+            cache = jax.tree_util.tree_unflatten(treedef, cols)
+        with span("serve.forward"):
+            new_tokens, new_cache = fn(cache, jnp.asarray(tokens))
         leaves = jax.tree_util.tree_leaves(new_cache)
         if len(leaves) != nleaves:
             raise ValueError("step_fn changed the cache pytree structure")
         created = []
         try:
             out = []
-            for b in range(nreq):
-                row = []
-                for i, leaf in enumerate(leaves):
-                    ref = DeviceRef(split(leaf, b, i))
-                    created.append(ref)
-                    row.append(ref)
-                out.append(tuple(row))
-            return np.asarray(jax.device_get(new_tokens)), tuple(out)
+            with span("serve.split"):
+                for b in range(nreq):
+                    row = []
+                    for i, leaf in enumerate(leaves):
+                        ref = DeviceRef(split(leaf, b, i))
+                        created.append(ref)
+                        row.append(ref)
+                    out.append(tuple(row))
+            with span("serve.readback"):
+                toks = np.asarray(jax.device_get(new_tokens))
+            return toks, tuple(out)
         except BaseException:
             # a failing split/read-back must not leak the per-request
             # refs already carved out — the step will be retried
@@ -573,7 +579,9 @@ class ServeEngine:
                     newcomers = self.batcher.take(free, bucket=bucket,
                                                   wait_s=0.0, max_wait_s=0.0)
                 else:
-                    newcomers = self.batcher.take(free, wait_s=0.02)
+                    with span("serve.batch_wait") as sp:
+                        newcomers = self.batcher.take(free, wait_s=0.02)
+                        sp.set_metadata(got=len(newcomers))
                 for req in newcomers:
                     self._admit(req, active)
             if not active:
@@ -596,64 +604,66 @@ class ServeEngine:
     # -- batch membership --------------------------------------------------
     def _admit(self, req: Request, active: List[_Active]) -> None:
         now = self._clock()
-        if req.deadline is not None and req.deadline <= now:
-            self._bump("expired")
-            if not req.future.done():
-                req.future.set_exception(DeadlineExceeded(
-                    f"request {req.id} expired while queued"))
-            return
-        created: List[DeviceRef] = []
-        try:
-            cache, first_token = self.init_fn(req.prompt)
-            refs = tree_wrap(cache, device=self.device, created=created)
-        except Exception as exc:
-            # a bad prompt fails its own request, never the engine — and
-            # a wrap that died mid-tree (one bad leaf after several good
-            # ones) must not leak the refs already created (shed-path
-            # leak regression)
-            for ref in created:
-                ref.release()
-            self._bump("failed")
-            if not req.future.done():
-                req.future.set_exception(exc)
-            return
-        leaves, treedef = jax.tree_util.tree_flatten(refs)
-        # init_fn may be a long prefill: re-check the deadline *after* it
-        # ran and release the just-built cache on the shed path instead
-        # of parking it in the batch for a doomed decode step
-        now = self._clock()
-        if req.deadline is not None and req.deadline <= now:
-            for ref in leaves:
-                ref.release()
-            self._bump("expired")
-            if not req.future.done():
-                req.future.set_exception(DeadlineExceeded(
-                    f"request {req.id} expired during cache init"))
-            return
-        if active:
-            # the prompt-shape bucket is only a proxy for cache
-            # compatibility; verify the real invariant so one malformed
-            # joiner sheds itself instead of crashing the whole batch in
-            # the worker's tree_unflatten/stack
-            seed = active[0]
-            if treedef != seed.treedef or \
-                    [(l.shape, l.dtype) for l in leaves] != \
-                    [(l.shape, l.dtype) for l in seed.leaves]:
-                for ref in leaves:
+        with span("serve.admit", request=req.id,
+                  queued_ms=(now - req.t_submit) * 1e3):
+            if req.deadline is not None and req.deadline <= now:
+                self._bump("expired")
+                if not req.future.done():
+                    req.future.set_exception(DeadlineExceeded(
+                        f"request {req.id} expired while queued"))
+                return
+            created: List[DeviceRef] = []
+            try:
+                cache, first_token = self.init_fn(req.prompt)
+                refs = tree_wrap(cache, device=self.device, created=created)
+            except Exception as exc:
+                # a bad prompt fails its own request, never the engine — and
+                # a wrap that died mid-tree (one bad leaf after several good
+                # ones) must not leak the refs already created (shed-path
+                # leak regression)
+                for ref in created:
                     ref.release()
                 self._bump("failed")
                 if not req.future.done():
-                    req.future.set_exception(ValueError(
-                        f"request {req.id}: cache structure does not match "
-                        "the running batch (init_fn inconsistent with the "
-                        "shape bucket)"))
+                    req.future.set_exception(exc)
                 return
-        req.last_token = first_token
-        active.append(_Active(req, leaves, treedef))
-        self._bump("joined")
-        with self._ct_lock:
-            self._counters["peak_batch"] = max(self._counters["peak_batch"],
-                                               len(active))
+            leaves, treedef = jax.tree_util.tree_flatten(refs)
+            # init_fn may be a long prefill: re-check the deadline *after* it
+            # ran and release the just-built cache on the shed path instead
+            # of parking it in the batch for a doomed decode step
+            now = self._clock()
+            if req.deadline is not None and req.deadline <= now:
+                for ref in leaves:
+                    ref.release()
+                self._bump("expired")
+                if not req.future.done():
+                    req.future.set_exception(DeadlineExceeded(
+                        f"request {req.id} expired during cache init"))
+                return
+            if active:
+                # the prompt-shape bucket is only a proxy for cache
+                # compatibility; verify the real invariant so one malformed
+                # joiner sheds itself instead of crashing the whole batch in
+                # the worker's tree_unflatten/stack
+                seed = active[0]
+                if treedef != seed.treedef or \
+                        [(l.shape, l.dtype) for l in leaves] != \
+                        [(l.shape, l.dtype) for l in seed.leaves]:
+                    for ref in leaves:
+                        ref.release()
+                    self._bump("failed")
+                    if not req.future.done():
+                        req.future.set_exception(ValueError(
+                            f"request {req.id}: cache structure does not "
+                            "match the running batch (init_fn inconsistent "
+                            "with the shape bucket)"))
+                    return
+            req.last_token = first_token
+            active.append(_Active(req, leaves, treedef))
+            self._bump("joined")
+            with self._ct_lock:
+                self._counters["peak_batch"] = max(
+                    self._counters["peak_batch"], len(active))
 
     def _leave(self, a, active: list,
                error: Optional[BaseException] = None) -> None:
@@ -729,50 +739,53 @@ class ServeEngine:
 
     # -- one decode step ---------------------------------------------------
     def _step(self, active: List[_Active]) -> None:
-        self._heal_pool()
-        self._note_step_gap()
-        payload = ("step",
-                   tuple(a.req.last_token for a in active),
-                   tuple(tuple(a.leaves) for a in active),
-                   active[0].treedef)
-        failed_before = self._scheduler.stats["failed"]
-        t0 = self._clock()
-        try:
-            # one chunk through the ChunkScheduler: its re-issue machinery
-            # retries a failed step on another live worker (the crashed
-            # one is dead to the pool) up to max_attempts
-            result = self._scheduler.run([payload],
-                                         timeout=self.step_timeout)[0]
-        except Exception as exc:
-            # permanent failure: every member surfaces it per-request;
-            # the engine itself keeps serving
+        with span("serve.step", step=self._counters["steps"],
+                  batch=len(active)):
+            self._heal_pool()
+            self._note_step_gap()
+            payload = ("step",
+                       tuple(a.req.last_token for a in active),
+                       tuple(tuple(a.leaves) for a in active),
+                       active[0].treedef)
+            failed_before = self._scheduler.stats["failed"]
+            t0 = self._clock()
+            try:
+                # one chunk through the ChunkScheduler: its re-issue machinery
+                # retries a failed step on another live worker (the crashed
+                # one is dead to the pool) up to max_attempts
+                with span("serve.dispatch"):
+                    result = self._scheduler.run(
+                        [payload], timeout=self.step_timeout)[0]
+            except Exception as exc:
+                # permanent failure: every member surfaces it per-request;
+                # the engine itself keeps serving
+                self._bump("requeues",
+                           self._scheduler.stats["failed"] - failed_before)
+                for a in list(active):
+                    self._leave(a, active, error=exc)
+                self._last_step_end = self._clock()
+                return
             self._bump("requeues",
                        self._scheduler.stats["failed"] - failed_before)
-            for a in list(active):
-                self._leave(a, active, error=exc)
-            self._last_step_end = self._clock()
-            return
-        self._bump("requeues",
-                   self._scheduler.stats["failed"] - failed_before)
-        self.queue.note_service_time(self._clock() - t0)
-        self._bump("steps")
-        self._bump("batch_slots", len(active))
-        tokens, new_caches = result
-        now = self._clock()
-        self._last_step_end = now
-        for a, tok, new_leaves in zip(list(active), tokens, new_caches):
-            for old in a.leaves:
-                old.release()
-            a.leaves = list(new_leaves)
-            token = tok.item() if hasattr(tok, "item") else tok
-            a.req.tokens.append(token)
-            a.req.last_token = token
-            self._bump("tokens")
-            if a.req.t_first is None:
-                a.req.t_first = now
-                self.ttft.record(now - a.req.t_submit)
-            if len(a.req.tokens) >= a.req.max_new_tokens:
-                self._leave(a, active)
+            self.queue.note_service_time(self._clock() - t0)
+            self._bump("steps")
+            self._bump("batch_slots", len(active))
+            tokens, new_caches = result
+            now = self._clock()
+            self._last_step_end = now
+            for a, tok, new_leaves in zip(list(active), tokens, new_caches):
+                for old in a.leaves:
+                    old.release()
+                a.leaves = list(new_leaves)
+                token = tok.item() if hasattr(tok, "item") else tok
+                a.req.tokens.append(token)
+                a.req.last_token = token
+                self._bump("tokens")
+                if a.req.t_first is None:
+                    a.req.t_first = now
+                    self.ttft.record(now - a.req.t_submit)
+                if len(a.req.tokens) >= a.req.max_new_tokens:
+                    self._leave(a, active)
 
     # ------------------------------------------------------------------
     # paged mode: prefill threads + the paged decode loop
