@@ -160,21 +160,10 @@ def wah_index_pipeline_actors(system, k: int, mode: str = "staged"):
         return ops.wah_interleave(fills, literals)
 
     def count_elements(index):
-        blocks, cnts = pallas_local_compact(index, bs=bs,
-                                            interpret=not ops.on_tpu())
-        return index, blocks, cnts
+        return pallas_local_compact(index, bs=bs, interpret=not ops.on_tpu())
 
-    def move_valid_elements(index, blocks, cnts):
-        n = index.shape[0]
-        counts = cnts[:, 0]
-        offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(counts)])
-        total = offsets[-1]
-        i = jnp.arange(n)
-        blk = jnp.clip(jnp.searchsorted(offsets, i, side="right") - 1,
-                       0, blocks.shape[0] - 1)
-        vals = blocks[blk, jnp.clip(i - offsets[blk], 0, bs - 1)]
-        out = jnp.where(i < total, vals, 0).astype(jnp.uint32)
-        return out, total.astype(jnp.int32)
+    def move_valid_elements(blocks, cnts):
+        return ops._place_blocks(blocks, cnts)
 
     rng = NDRange(dim_vec(k))
     rng_sc = NDRange(dim_vec(2 * k), local_dims=dim_vec(bs))
@@ -183,10 +172,9 @@ def wah_index_pipeline_actors(system, k: int, mode: str = "staged"):
                      nd_range=rng, name="prepare_index")(prepare_index)
     count = kernel(In(jnp.uint32),
                    Out(jnp.uint32, as_ref=True),
-                   Out(jnp.uint32, as_ref=True),
                    Out(jnp.int32, as_ref=True),
                    nd_range=rng_sc, name="count_elements")(count_elements)
-    move = kernel(In(jnp.uint32), In(jnp.uint32), In(jnp.int32),
+    move = kernel(In(jnp.uint32), In(jnp.int32),
                   Out(jnp.uint32), Out(jnp.int32),
                   nd_range=rng_sc, name="move_valid_elements")(
                       move_valid_elements)

@@ -8,8 +8,8 @@ sort/interleave family, and the oracle for attention (where interpreted
 execution would be prohibitively slow at model shapes).
 
 These wrappers also hold the XLA halves of the TPU adaptations: the
-compaction gather and the radix-scatter permutation (see the kernel module
-docstrings for why the irregular move lives in XLA on TPU).
+compaction's prefix-sum scatter and the radix-scatter permutation (see the
+kernel module docstrings for why the irregular move lives in XLA on TPU).
 """
 from __future__ import annotations
 
@@ -88,19 +88,29 @@ def stream_compact(x, *, bs: int = 256, drop_value: int = 0,
     blocks, counts = pallas_local_compact(x.astype(jnp.uint32), bs=bs,
                                           drop_value=drop_value,
                                           interpret=interp)
-    counts = counts[:, 0]                                 # (nb,)
-    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                               jnp.cumsum(counts)])       # (nb+1,)
-    total = offsets[-1]
-    # Billeter phase 3 as one gather: output i comes from block
-    # searchsorted(offsets, i) at local index i - offsets[block].
-    i = jnp.arange(n)
-    blk = jnp.searchsorted(offsets, i, side="right") - 1
-    blk = jnp.clip(blk, 0, blocks.shape[0] - 1)
-    local = i - offsets[blk]
-    vals = blocks[blk, jnp.clip(local, 0, bs - 1)]
-    out = jnp.where(i < total, vals, 0).astype(x.dtype)
-    return out, total.astype(jnp.int32)
+    out, total = _place_blocks(blocks, counts)
+    return out.astype(x.dtype), total
+
+
+def _place_blocks(blocks, counts):
+    """Billeter phase 3: concatenate each block's valid prefix, in order.
+
+    ``blocks[b, :counts[b, 0]]`` are block ``b``'s survivors (the layout
+    of ``pallas_local_compact``), so survivor ``j`` lands at ``base[b] + j``
+    with ``base`` the exclusive prefix sum of the counts: one scatter, no
+    search. Every dropped lane gets an out-of-range index of its own (so
+    ``unique_indices`` holds) and leaves its slot zero.
+    → ``(out, total)``, ``out`` prefix-valid and as long as ``blocks``.
+    """
+    nb, bs = blocks.shape
+    n = nb * bs
+    base = jnp.cumsum(counts, axis=0) - counts                # (nb, 1)
+    lane = jnp.arange(bs, dtype=jnp.int32)
+    slot = jnp.arange(nb, dtype=jnp.int32)[:, None] * bs + lane
+    dest = jnp.where(lane < counts, base + lane, n + slot)
+    out = jnp.zeros(n, blocks.dtype).at[dest.reshape(-1)].set(
+        blocks.reshape(-1), mode="drop", unique_indices=True)
+    return out, jnp.sum(counts).astype(jnp.int32)
 
 
 # ----------------------------------------------------------------------------

@@ -13,9 +13,11 @@ A grid step takes up to eight such blocks, one per sublane row of its
 tile, since the TPU lowering needs 8-row tiles (or the whole array).
 
 One Pallas pass emits, per block, the locally-compacted values and the
-valid count. The global move (Billeter's phase 3) is a single XLA gather
-assembled from the per-block counts in ``ops.stream_compact`` — irregular
-data movement is XLA's job on TPU; regular compute stays in the kernel.
+valid count. The global move (Billeter's phase 3) is one XLA scatter in
+``ops.stream_compact``: survivor ``j`` of block ``b`` goes to slot
+``base[b] + j``, ``base`` the exclusive prefix sum of the counts —
+irregular data movement is XLA's job on TPU; regular compute stays in the
+kernel.
 """
 from __future__ import annotations
 

@@ -1,4 +1,8 @@
 """Per-kernel allclose sweeps vs the ref.py oracles (interpret=True on CPU)."""
+import functools
+
+import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -51,15 +55,89 @@ def test_mandelbrot_row_offset_consistency():
 
 
 # ----------------------------------------------------------------------------
-@pytest.mark.parametrize("n,bs", [(256, 256), (1024, 256), (2048, 512)])
-@pytest.mark.parametrize("density", [0.0, 0.3, 1.0])
-def test_stream_compact_sweep(n, bs, density):
-    mask = RNG.random(n) < density
-    x = (RNG.integers(1, 2**32, n, dtype=np.uint64).astype(np.uint32)) * mask
-    got, cnt = ops.stream_compact(jnp.asarray(x), bs=bs, impl="pallas")
-    want, wcnt = ref.stream_compact(jnp.asarray(x))
-    assert int(cnt) == int(wcnt) == int(mask.sum())
+def _compaction_input(pattern, n, bs, drop_value):
+    """``n`` uint32 whose survivors (entries other than ``drop_value``)
+    follow ``pattern``: a density, or a layout over the ``bs``-blocks."""
+    if pattern == "wah":        # build_wah_index's step (5) input
+        k = n // 2
+        seg = np.arange(k) < k // 2          # valid segments: first half only
+        gap = seg & (RNG.random(k) < 0.4)
+        fills = np.where(gap, (1 << 31) | RNG.integers(1, 99, k), 0)
+        lits = np.where(seg, RNG.integers(1, 2**31, k), 0)
+        return np.asarray(ref.wah_interleave(
+            jnp.asarray(fills.astype(np.uint32)),
+            jnp.asarray(lits.astype(np.uint32))))
+    blk = np.arange(n) // bs
+    last = n // bs - 1
+    if isinstance(pattern, float):
+        keep = RNG.random(n) < pattern
+    else:
+        keep = {"gaps": blk % 3 != 1,             # empty blocks between full
+                "last-only": (blk == last) & (RNG.random(n) < 0.5),
+                "partial-last": (blk < last) | (RNG.random(n) < 0.5),
+                }[pattern]
+    vals = RNG.integers(1, 2**32, n, dtype=np.uint64).astype(np.uint32)
+    if drop_value:              # zeros survive when they are not dropped
+        vals[RNG.random(n) < 0.2] = 0
+        vals[vals == drop_value] = 1
+    return np.where(keep, vals, np.uint32(drop_value)).astype(np.uint32)
+
+
+_COMPACT_CASES = [
+    pytest.param(d, n, bs, 0, id=f"{d}-{n}-{bs}")
+    for d in (0.0, 0.3, 1.0)
+    for n, bs in ((256, 256), (1024, 256), (2048, 512))
+] + [
+    pytest.param("gaps", 2048, 256, 0, id="empty-between-full"),
+    pytest.param(0.0, 4096, 256, 0, id="all-empty"),
+    pytest.param(1.0, 4096, 256, 0, id="all-full"),
+    pytest.param("last-only", 2048, 256, 0, id="last-block-only"),
+    pytest.param("partial-last", 2048, 256, 0, id="partial-last-block"),
+    pytest.param(0.5, 2048, 256, 0x7FFFFFFF, id="drop-value-large"),
+    pytest.param("gaps", 2048, 512, 7, id="drop-value-7-gaps"),
+    pytest.param("wah", 4096, 256, 0, id="wah-interleaved-2e12"),
+]
+
+
+@pytest.mark.parametrize("pattern,n,bs,drop_value", _COMPACT_CASES)
+def test_stream_compact_sweep(pattern, n, bs, drop_value):
+    x = _compaction_input(pattern, n, bs, drop_value)
+    got, cnt = ops.stream_compact(jnp.asarray(x), bs=bs, drop_value=drop_value,
+                                  impl="pallas")
+    want, wcnt = ref.stream_compact(jnp.asarray(x), drop_value)
+    assert int(cnt) == int(wcnt) == int((x != drop_value).sum())
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_stream_compact_moves_by_prefix_sums():
+    """The global move addresses each survivor from the prefix sum of the
+    block counts: outside the Pallas kernel no loop (a per-slot search) and
+    no gather is left."""
+    jaxpr = jax.make_jaxpr(functools.partial(ops.stream_compact,
+                                             impl="pallas"))(
+        jnp.zeros(2048, jnp.uint32))
+    seen = set()
+
+    def walk(j):
+        for eqn in j.eqns:
+            seen.add(eqn.primitive.name)
+            if eqn.primitive.name != "pallas_call":
+                for sub in _subjaxprs(eqn.params.values()):
+                    walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert "pallas_call" in seen and "scatter" in seen, seen
+    assert not seen & {"scan", "while", "gather"}, seen
+
+
+def _subjaxprs(values):
+    for v in values:
+        if isinstance(v, jax.extend.core.ClosedJaxpr):
+            yield v.jaxpr
+        elif isinstance(v, jax.extend.core.Jaxpr):
+            yield v
+        elif isinstance(v, (tuple, list)):
+            yield from _subjaxprs(v)
 
 
 def test_stream_compact_order_preserved():
