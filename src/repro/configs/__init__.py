@@ -4,16 +4,16 @@ Every assigned architecture has ``configs/<id>.py`` with ``config()``
 (the published widths and depth: what ``--full`` runs on a chip, and what
 the dry-run plans for a pod) and ``smoke_config()`` (reduced, CPU-testable).
 """
-from . import (dbrx, llama3_8b, mamba2_130m, nemotron4_340b, phi35_moe,
-               qwen2_vl, qwen3_1p7b, qwen15_32b, recurrentgemma_9b,
-               whisper_tiny)
+from . import (dbrx, llama3_8b, mamba2_130m, moonlight_16b_a3b,
+               nemotron4_340b, phi35_moe, qwen2_vl, qwen3_1p7b, qwen15_32b,
+               recurrentgemma_9b, whisper_tiny)
 from .base import ModelConfig
 
 _MODULES = {
     m.ARCH: m
     for m in (phi35_moe, dbrx, whisper_tiny, qwen2_vl, mamba2_130m,
               qwen3_1p7b, qwen15_32b, nemotron4_340b, llama3_8b,
-              recurrentgemma_9b)
+              recurrentgemma_9b, moonlight_16b_a3b)
 }
 
 ARCHS = tuple(_MODULES)

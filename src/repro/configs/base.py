@@ -21,6 +21,37 @@ class MoEConfig:
     aux_loss_weight: float = 0.01
     #: routing group length; capacity C = ⌈k·g/E·cf⌉ is independent of S
     group_size: int = 4096
+    #: routed expert width (``moe_intermediate_size``); 0 → ``d_ff``
+    d_expert: int = 0
+    #: shared experts, applied to every token as one SwiGLU of width
+    #: ``n_shared * d_expert`` (DeepSeek-MoE)
+    n_shared: int = 0
+    #: "softmax": top-k of the softmax, renormalised; "sigmoid": DeepSeek-V3
+    #: ``noaux_tc`` with one group (sigmoid scores, a correction bias added
+    #: for the choice only, the chosen scores renormalised and scaled by
+    #: ``routed_scaling``)
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    #: leading dense layers (SwiGLU of ``d_ff``) before the expert layers
+    #: (``first_k_dense_replace``)
+    first_dense: int = 0
+    #: the routed experts this chip holds, ``[lo, hi)`` (expert parallelism):
+    #: the router still scores all ``n_experts``; the layer computes only the
+    #: held experts' part. None → all of them
+    held: Optional[Tuple[int, int]] = None
+
+    def held_range(self) -> Tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_experts)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3) with no query LoRA.
+    ``kv_lora_rank`` 0 means the model uses GQA attention instead."""
+    kv_lora_rank: int = 0          # latent width of the K/V cache
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64     # rotary key, shared by all heads
+    v_head_dim: int = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,6 +95,10 @@ class ModelConfig:
     attn_bias: bool = False            # qwen1.5 QKV bias
     attn_logit_softcap: Optional[float] = None
     rope_theta: float = 10_000.0
+    #: rotary on interleaved pairs (x[2i], x[2i+1]) as DeepSeek applies it;
+    #: default half-split (NeoX)
+    rope_interleave: bool = False
+    norm_eps: float = 1e-6
     m_rope: bool = False               # qwen2-vl 3-axis rotary
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
     # mlp
@@ -72,6 +107,7 @@ class ModelConfig:
     tie_embeddings: bool = False
     # sub-configs
     moe: MoEConfig = MoEConfig()
+    mla: MLAConfig = MLAConfig()
     ssm: SSMConfig = SSMConfig()
     hybrid: HybridConfig = HybridConfig()
     encdec: EncDecConfig = EncDecConfig()
@@ -94,6 +130,14 @@ class ModelConfig:
         return self.d_model // self.n_heads if self.n_heads else 0
 
     @property
+    def expert_width(self) -> int:
+        return self.moe.d_expert or self.d_ff
+
+    @property
+    def uses_mla(self) -> bool:
+        return self.mla.kv_lora_rank > 0
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -107,16 +151,27 @@ class ModelConfig:
     def dtype(self) -> jnp.dtype:
         return jnp.dtype(self.compute_dtype)
 
+    def _mla_params(self) -> int:
+        m, d, h = self.mla, self.d_model, self.n_heads
+        return (d * h * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+                + d * (m.kv_lora_rank + m.qk_rope_head_dim) + m.kv_lora_rank
+                + m.kv_lora_rank * h * (m.qk_nope_head_dim + m.v_head_dim)
+                + h * m.v_head_dim * d)
+
     def param_count(self) -> int:
         """Approximate parameter count (used for MODEL_FLOPS and reporting)."""
         d, f, v, l = self.d_model, self.d_ff, self.vocab_size, self.n_layers
         hd = self.resolved_head_dim
         qkvo = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + \
             self.n_heads * hd * d
+        if self.uses_mla:
+            qkvo = self._mla_params()
         gated = self.mlp in ("swiglu", "geglu")
         mlp = d * f * (3 if gated else 2)
+        dense_mlp = mlp
         if self.family == "moe":
-            mlp *= self.moe.n_experts
+            mlp = d * self.expert_width * (3 if gated else 2) * (
+                self.moe.n_experts + self.moe.n_shared)
             mlp += d * self.moe.n_experts  # router
         if self.family == "ssm":
             di = self.ssm.expand * d
@@ -138,15 +193,17 @@ class ModelConfig:
             # encoder layers: self-attn + mlp; decoder adds cross-attn
             enc = self.encdec.n_enc_layers * (qkvo + mlp)
             qkvo = 2 * qkvo  # decoder self + cross
-        return int(l * (qkvo + mlp) + emb + enc)
+        # leading dense layers hold a dense MLP in place of the experts
+        first = self.moe.first_dense if self.family == "moe" else 0
+        return int(l * (qkvo + mlp) + first * (dense_mlp - mlp) + emb + enc)
 
     def active_param_count(self) -> int:
         """Active params per token (MoE: only top_k experts)."""
         if self.family != "moe":
             return self.param_count()
-        d, f, l = self.d_model, self.d_ff, self.n_layers
+        d, l = self.d_model, self.n_layers - self.moe.first_dense
         gated = self.mlp in ("swiglu", "geglu")
-        per_expert = d * f * (3 if gated else 2)
+        per_expert = d * self.expert_width * (3 if gated else 2)
         total = self.param_count()
         inactive = l * per_expert * (self.moe.n_experts - self.moe.top_k)
         return int(total - inactive)

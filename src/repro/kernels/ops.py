@@ -23,12 +23,13 @@ from . import ref
 from .flash_attention import pallas_flash_attention
 from .mandelbrot import pallas_mandelbrot
 from .matmul import pallas_matmul
+from .moe_held import pallas_moe_held_experts
 from .radix_sort import pallas_radix_pass
 from .stream_compact import pallas_local_compact
 from .wah import pallas_wah_interleave
 
 __all__ = ["matmul", "mandelbrot", "stream_compact", "radix_sort",
-           "wah_interleave", "flash_attention", "on_tpu"]
+           "wah_interleave", "flash_attention", "moe_held_experts", "on_tpu"]
 
 
 def on_tpu() -> bool:
@@ -164,3 +165,82 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return pallas_flash_attention(q, k, v, causal=causal, window=window,
                                       bq=bq, bk=bk, interpret=not on_tpu())
     return ref.flash_attention(q, k, v, causal=causal, window=window)
+
+
+# ----------------------------------------------------------------------------
+#: rows of a held-expert kernel tile; each expert's group is padded to whole
+#: tiles
+MOE_ROWS = 16
+
+
+def _expert_tile(d: int, f: int, itemsize: int,
+                 budget: int = 48 << 20) -> int:
+    """The widest F tile (a multiple of 128 dividing F) whose gate, up and
+    down blocks, double-buffered, fit ``budget`` bytes of VMEM."""
+    if f % 128:
+        return f
+    fits = [t for t in range(128, f + 1, 128)
+            if f % t == 0 and 2 * 3 * d * t * itemsize <= budget]
+    return max(fits) if fits else 128
+
+
+def moe_held_experts(x, expert_idx, expert_w, w_gate, w_up, w_down, lo: int,
+                     *, layer=None, impl: str = "auto",
+                     tf: Optional[int] = None):
+    """Dropless routed part of an expert layer, over the experts held here.
+
+    ``x [T, D]``; ``expert_idx``/``expert_w [T, k]``: each token's chosen
+    experts (global ids) and their routing weights; ``w_* [E_h, ...]``: the
+    held experts ``lo .. lo + E_h - 1``, or with ``layer`` (an int32
+    scalar) those weights stacked over layers, ``[L, E_h, ...]``, of which
+    that layer is used. Choices of experts held elsewhere add nothing.
+    → ``(y [T, D], held_choices, touched_experts)``, the last two int32
+    scalars: (token, choice) pairs that landed here, and held experts at
+    least one token chose.
+
+    Every token's choices are kept (no capacity): the choices are sorted by
+    expert into groups of whole ``MOE_ROWS``-row tiles and
+    ``pallas_moe_held_experts`` runs each chosen expert once over its
+    group; "ref" runs every held expert on every token
+    (``ref.moe_held_experts``).
+    """
+    t, k = expert_idx.shape
+    if layer is None:
+        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+        layer = 0
+    ne = w_gate.shape[1]
+    local = expert_idx.reshape(-1).astype(jnp.int32) - lo
+    held = (local >= 0) & (local < ne)
+    onehot = (held[:, None] & (local[:, None] == jnp.arange(ne))
+              ).astype(jnp.int32)                                # [T*k, E_h]
+    counts = jnp.sum(onehot, axis=0)
+    n_held = jnp.sum(counts)
+    touched = jnp.sum((counts > 0).astype(jnp.int32))
+    use, interp = _use_pallas(impl)
+    if not use:
+        return (ref.moe_held_experts(x, expert_idx, expert_w, w_gate[layer],
+                                     w_up[layer], w_down[layer], lo),
+                n_held, touched)
+    tm = MOE_ROWS
+    tiles = (counts + tm - 1) // tm
+    start = jnp.cumsum(tiles * tm) - tiles * tm
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
+    m = -(-(t * k + ne * (tm - 1)) // tm) * tm
+    pos = jnp.where(held, start[jnp.clip(local, 0, ne - 1)] + rank, m)
+    tok = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
+    src = jnp.full((m,), t, jnp.int32).at[pos].set(tok, mode="drop")
+    row_w = jnp.zeros((m,), jnp.float32).at[pos].set(
+        expert_w.reshape(-1).astype(jnp.float32), mode="drop")
+    xs = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])[src]
+    tf = tf or _expert_tile(x.shape[1], w_gate.shape[3],
+                            jnp.dtype(w_gate.dtype).itemsize)
+
+    def run(xs):
+        return pallas_moe_held_experts(xs, row_w[:, None], start, tiles,
+                                       w_gate, w_up, w_down, layer, tm=tm,
+                                       tf=tf, interpret=interp)
+
+    ys = jax.lax.cond(n_held > 0, run, jnp.zeros_like, xs)
+    picked = jnp.where(held[:, None], ys[jnp.minimum(pos, m - 1)], 0)
+    y = jnp.sum(picked.reshape(t, k, -1).astype(jnp.float32), axis=1)
+    return y.astype(x.dtype), n_held, touched

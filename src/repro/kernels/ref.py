@@ -141,3 +141,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def moe_held_experts(x, expert_idx, expert_w, w_gate, w_up, w_down, lo: int):
+    """Oracle of ``ops.moe_held_experts``: every held expert's SwiGLU on
+    every token, weighted by that token's routing weight for the expert (0
+    where it did not choose it). x [T, D] → [T, D]."""
+    ne = w_gate.shape[0]
+    gates = jnp.sum(jnp.where(
+        (expert_idx[..., None] - lo) == jnp.arange(ne),
+        expert_w[..., None].astype(jnp.float32), 0.0), axis=1)    # [T, E_h]
+    g = jnp.einsum("td,edf->tef", x, w_gate)
+    u = jnp.einsum("td,edf->tef", x, w_up)
+    h = jax.nn.silu(g) * u
+    y = jnp.einsum("tef,efd->ted", h, w_down)
+    return jnp.einsum("ted,te->td", y.astype(jnp.float32),
+                      gates).astype(x.dtype)

@@ -54,6 +54,24 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
+                           theta: float) -> jax.Array:
+    """x: [B,S,H,D]; positions: [B,S]. Rotates the interleaved pairs
+    ``(x[2i], x[2i+1])`` by ``positions * theta^(-2i/D)`` and returns them
+    de-interleaved (rotated evens, then rotated odds), as DeepSeek's
+    ``apply_rotary_pos_emb`` does; q and k take the same permutation, so
+    their dot products are those of the pairs rotated in place."""
+    d = x.shape[-1]
+    freqs = rope_freqs(d, theta)
+    angles = positions[..., None].astype(jnp.float32) * freqs     # [B,S,d/2]
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.astype(x.dtype)
+
+
 def apply_m_rope(x: jax.Array, positions3: jax.Array, theta: float,
                  sections: Tuple[int, int, int]) -> jax.Array:
     """Multimodal RoPE (Qwen2-VL, arXiv:2409.12191).

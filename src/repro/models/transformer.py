@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 
 from . import attention as attn_mod
+from . import mla as mla_mod
 from . import moe as moe_mod
 from . import rglru as rglru_mod
 from . import ssm as ssm_mod
@@ -57,7 +58,16 @@ def apply_remat(body, remat: str):
     raise ValueError(remat)
 
 
+#: block kinds made of attention and a feed-forward: "attn" (experts in an
+#: moe model, else a dense MLP) and "dense" (a dense MLP of ``d_ff``: the
+#: leading layers of a model with ``moe.first_dense``)
+ATTN_KINDS = ("attn", "dense")
+
+
 def layer_groups(cfg: ModelConfig) -> List[Tuple[Tuple[str, ...], int]]:
+    if cfg.family == "moe" and cfg.moe.first_dense:
+        return [(("dense",), cfg.moe.first_dense),
+                (("attn",), cfg.n_layers - cfg.moe.first_dense)]
     if cfg.family in ("dense", "moe", "vlm"):
         return [(("attn",), cfg.n_layers)]
     if cfg.family == "ssm":
@@ -74,11 +84,12 @@ def layer_groups(cfg: ModelConfig) -> List[Tuple[Tuple[str, ...], int]]:
 
 def _init_block(key, cfg: ModelConfig, kind: str, dtype) -> Params:
     k1, k2, k3 = jax.random.split(key, 3)
-    if kind == "attn":
+    if kind in ATTN_KINDS:
         p = {"norm1": init_norm(cfg.d_model, cfg.norm, dtype),
-             "attn": attn_mod.init_attention(k1, cfg, dtype),
+             "attn": (mla_mod.init_mla(k1, cfg, dtype) if cfg.uses_mla
+                      else attn_mod.init_attention(k1, cfg, dtype)),
              "norm2": init_norm(cfg.d_model, cfg.norm, dtype)}
-        if cfg.family == "moe":
+        if cfg.family == "moe" and kind == "attn":
             p["moe"] = moe_mod.init_moe(k2, cfg, dtype)
         else:
             p["mlp"] = init_mlp(k2, cfg.d_model, cfg.d_ff, cfg.mlp, dtype)
@@ -128,14 +139,23 @@ def _apply_unit(unit_params, cfg: ModelConfig, unit: Tuple[str, ...],
     from repro.dist import api as dist_api
     x = dist_api.hint(x)
     for block, kind in zip(unit_params, unit):
-        if kind == "attn":
+        if kind in ATTN_KINDS:
             window = cfg.hybrid.window if cfg.family == "hybrid" else None
-            h = apply_norm(block["norm1"], x, cfg.norm)
-            x = x + attn_mod.attention(block["attn"], cfg, h, positions,
-                                       causal=True, window=window,
-                                       impl=attn_impl)
-            h = apply_norm(block["norm2"], x, cfg.norm)
-            if "moe" in block:
+            h = apply_norm(block["norm1"], x, cfg.norm, cfg.norm_eps)
+            if cfg.uses_mla:
+                x = x + mla_mod.mla_attention(block["attn"], cfg, h,
+                                              positions)
+            else:
+                x = x + attn_mod.attention(block["attn"], cfg, h, positions,
+                                           causal=True, window=window,
+                                           impl=attn_impl)
+            h = apply_norm(block["norm2"], x, cfg.norm, cfg.norm_eps)
+            if "moe" in block and cfg.moe.held is not None:
+                # a layer holding a share of its experts: dropless, every
+                # held expert on every token (no aux loss)
+                y, _ = moe_mod.apply_moe_dropless(block["moe"], cfg, h,
+                                                  impl="ref")
+            elif "moe" in block:
                 y, a = moe_mod.apply_moe(block["moe"], cfg, h)
                 aux = aux + a
             else:
@@ -191,7 +211,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array, *,
             for ci in range(count):
                 (x, aux), _ = body((x, aux), jax.tree.map(lambda a: a[ci], gp))
 
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
     logits = x @ head.astype(x.dtype)
     return logits, aux
@@ -232,7 +252,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
     for unit, count in layer_groups(cfg):
         unit_caches = []
         for kind in unit:
-            if kind == "attn":
+            if kind in ATTN_KINDS and cfg.uses_mla:
+                unit_caches.append(mla_mod.init_mla_cache(
+                    cfg, batch, max_len, dtype, count))
+            elif kind in ATTN_KINDS:
                 unit_caches.append(attn_mod.init_kv_cache(
                     cfg, batch,
                     max_len if cfg.family != "hybrid"
@@ -244,35 +267,77 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
                 unit_caches.append(rglru_mod.init_rglru_cache(cfg, batch, dtype,
                                                               count))
         groups.append(unit_caches)
-    return {"len": jnp.zeros((), jnp.int32), "groups": groups}
+    cache = {"len": jnp.zeros((), jnp.int32), "groups": groups}
+    if cfg.family == "moe":
+        # the last step's expert counters, summed over its expert layers:
+        # (token, choice) pairs routed to held experts, held experts chosen
+        cache["moe_counts"] = jnp.zeros((2,), jnp.int32)
+    return cache
+
+
+#: the routed experts' weights of an expert block
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_out")
+
+
+def _split_experts(unit_params: list) -> Tuple[list, list]:
+    """A unit's parameters without the routed experts' weights, and those
+    weights (``None`` for a block without experts). Decode hands the expert
+    kernel a group's whole stacks and the layer, not a per-layer slice: a
+    slice as a kernel's operand is copied out, every held expert, each
+    layer of each step."""
+    rest, experts = [], []
+    for block in unit_params:
+        if "moe" not in block:
+            rest.append(block)
+            experts.append(None)
+            continue
+        moe = dict(block["moe"])
+        experts.append({k: moe.pop(k) for k in EXPERT_WEIGHTS})
+        rest.append(dict(block, moe=moe))
+    return rest, experts
 
 
 def _decode_unit(unit_params, unit_cache, cfg: ModelConfig,
                  unit: Tuple[str, ...], x: jax.Array, cache_len,
-                 positions) -> Tuple[jax.Array, list]:
+                 positions, experts=None, layer=None
+                 ) -> Tuple[jax.Array, list, Optional[jax.Array]]:
+    """→ (x, the unit's new caches, its expert counters or None).
+    ``experts``/``layer``: the group's stacked routed-expert weights from
+    :func:`_split_experts`, and this unit's index in the group."""
     new_caches = []
-    for block, cache, kind in zip(unit_params, unit_cache, unit):
-        if kind == "attn":
+    counts = None
+    experts = experts or [None] * len(unit)
+    for block, cache, kind, stacks in zip(unit_params, unit_cache, unit,
+                                          experts):
+        if kind in ATTN_KINDS:
             window = cfg.hybrid.window if cfg.family == "hybrid" else None
-            h = apply_norm(block["norm1"], x, cfg.norm)
-            if window is not None:
+            h = apply_norm(block["norm1"], x, cfg.norm, cfg.norm_eps)
+            if cfg.uses_mla:
+                out, c_kv, k_pe = mla_mod.decode_mla(
+                    block["attn"], cfg, h, cache["c_kv"], cache["k_pe"],
+                    cache_len, positions)
+                new_cache = {"c_kv": c_kv, "k_pe": k_pe}
+            else:
                 # ring-buffer cache for local attention: slot = len % capacity
-                slot = jnp.remainder(cache_len, cache["k"].shape[1])
+                slot = (None if window is None
+                        else jnp.remainder(cache_len, cache["k"].shape[1]))
                 out, k, v = attn_mod.decode_attention(
                     block["attn"], cfg, h, cache["k"], cache["v"], cache_len,
                     positions, window=None, write_pos=slot)
-            else:
-                out, k, v = attn_mod.decode_attention(
-                    block["attn"], cfg, h, cache["k"], cache["v"], cache_len,
-                    positions, window=None)
+                new_cache = {"k": k, "v": v}
             x = x + out
-            h = apply_norm(block["norm2"], x, cfg.norm)
+            h = apply_norm(block["norm2"], x, cfg.norm, cfg.norm_eps)
             if "moe" in block:
-                y, _ = moe_mod.apply_moe(block["moe"], cfg, h)
+                # serving never drops a token
+                p = block["moe"] if stacks is None else dict(block["moe"],
+                                                             **stacks)
+                y, c = moe_mod.apply_moe_dropless(
+                    p, cfg, h, layer=None if stacks is None else layer)
+                counts = c if counts is None else counts + c
             else:
                 y = apply_mlp(block["mlp"], h, cfg.mlp)
             x = x + y
-            new_caches.append({"k": k, "v": v})
+            new_caches.append(new_cache)
         elif kind == "ssm":
             h = apply_norm(block["norm1"], x, cfg.norm)
             y, state, conv = ssm_mod.decode_ssm(block["ssm"], cfg, h,
@@ -287,7 +352,7 @@ def _decode_unit(unit_params, unit_cache, cfg: ModelConfig,
             h = apply_norm(block["norm2"], x, cfg.norm)
             x = x + apply_mlp(block["mlp"], h, cfg.mlp)
             new_caches.append({"state": state, "conv": conv})
-    return x, new_caches
+    return x, new_caches, counts
 
 
 def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -304,27 +369,40 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: jax.Array,
     x = embed_inputs(params, cfg, tokens, vision_embeds)
 
     new_groups = []
+    moe_counts = None
     for gi, (unit, count) in enumerate(layer_groups(cfg)):
         gp = params["groups"][gi]
         gc = cache["groups"][gi]
+        gp, experts = _split_experts(gp)
+        if not any(experts):
+            experts = None
 
-        def body(x, inp, unit=unit):
-            layer_params, layer_cache = inp
-            x, new_cache = _decode_unit(layer_params, layer_cache, cfg, unit,
-                                        x, cache_len, positions)
-            return x, new_cache
+        def body(x, inp, unit=unit, experts=experts):
+            layer_params, layer_cache, layer = inp
+            x, new_cache, counts = _decode_unit(layer_params, layer_cache,
+                                                cfg, unit, x, cache_len,
+                                                positions, experts, layer)
+            return x, (new_cache, counts)
 
+        index = jnp.arange(count, dtype=jnp.int32) if experts else None
         if cfg.scan_layers:
-            x, new_gc = jax.lax.scan(body, x, (gp, gc))
+            x, (new_gc, counts) = jax.lax.scan(body, x, (gp, gc, index))
         else:
             outs = []
             for ci in range(count):
-                x, nc = body(x, jax.tree.map(lambda a: a[ci], (gp, gc)))
+                x, nc = body(x, jax.tree.map(lambda a: a[ci],
+                                             (gp, gc, index)))
                 outs.append(nc)
-            new_gc = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+            new_gc, counts = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
         new_groups.append(new_gc)
+        if counts is not None:
+            counts = jnp.sum(counts, axis=0)
+            moe_counts = counts if moe_counts is None else moe_counts + counts
 
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
     logits = x @ head.astype(x.dtype)
-    return logits, {"len": cache_len + 1, "groups": new_groups}
+    new_cache = {"len": cache_len + 1, "groups": new_groups}
+    if "moe_counts" in cache:
+        new_cache["moe_counts"] = moe_counts
+    return logits, new_cache

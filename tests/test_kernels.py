@@ -229,3 +229,82 @@ def test_flash_attention_bf16():
     want = ref.flash_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), rtol=3e-2, atol=3e-2)
+
+
+# ----------------------------------------------------------------------------
+def _held_case(case, t, k, e, lo, ne):
+    """Each token's chosen experts (global ids; held: lo .. lo+ne-1)."""
+    if case == "no-token-on-a-held-expert":
+        ids = [[x for x in range(e) if not lo <= x < lo + ne][:k]] * t
+    elif case == "all-tokens-on-one-expert":
+        ids = [[lo + 1] + [x for x in range(e) if not lo <= x < lo + ne][:k - 1]] * t
+    else:  # uneven groups: a skewed draw, some held experts left idle
+        p = np.arange(e, 0, -1, dtype=np.float64) ** 2
+        ids = [RNG.choice(e, k, replace=False, p=p / p.sum()) for _ in range(t)]
+    return jnp.asarray(np.asarray(ids), jnp.int32)
+
+
+@pytest.mark.parametrize("case", ["no-token-on-a-held-expert",
+                                  "all-tokens-on-one-expert", "uneven-groups"])
+@pytest.mark.parametrize("t,tf", [(5, 128), (40, 256)])
+def test_moe_held_experts_matches_einsum(case, t, tf):
+    """The grouped kernel (interpreted) against every held expert run on
+    every token, masked by the routing weights: rows sorted by expert into
+    16-row tiles, groups longer than a tile, experts no token chose."""
+    k, e, lo, ne, d, f = 3, 12, 4, 6, 128, 256
+    idx = _held_case(case, t, k, e, lo, ne)
+    w = jnp.asarray(RNG.random((t, k)), jnp.float32)
+    x = jnp.asarray(RNG.standard_normal((t, d)), jnp.float32)
+    wg, wu = (jnp.asarray(RNG.standard_normal((ne, d, f)) / 12, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(RNG.standard_normal((ne, f, d)) / 16, jnp.float32)
+    got, n_held, touched = ops.moe_held_experts(x, idx, w, wg, wu, wd, lo,
+                                                impl="pallas", tf=tf)
+    want = ref.moe_held_experts(x, idx, w, wg, wu, wd, lo)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4,
+                               atol=1e-5)
+    local = np.asarray(idx) - lo
+    on_held = (local >= 0) & (local < ne)
+    assert int(n_held) == int(on_held.sum())
+    assert int(touched) == len(set(local[on_held].tolist()))
+    if case == "no-token-on-a-held-expert":
+        assert int(n_held) == 0 and not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_moe_held_experts_uses_the_given_layer_of_stacked_weights(layer):
+    """Weights stacked over layers, the layer a traced scalar (as decode's
+    layer scan passes them): the kernel computes that layer's experts."""
+    t, k, e, lo, ne, d, f, nl = 24, 3, 12, 4, 6, 128, 256, 3
+    idx = _held_case("uneven-groups", t, k, e, lo, ne)
+    w = jnp.asarray(RNG.random((t, k)), jnp.float32)
+    x = jnp.asarray(RNG.standard_normal((t, d)), jnp.float32)
+    wg, wu = (jnp.asarray(RNG.standard_normal((nl, ne, d, f)) / 12,
+                          jnp.float32) for _ in range(2))
+    wd = jnp.asarray(RNG.standard_normal((nl, ne, f, d)) / 16, jnp.float32)
+    run = jax.jit(lambda i: ops.moe_held_experts(
+        x, idx, w, wg, wu, wd, lo, layer=i, impl="pallas", tf=128))
+    got, n_held, _ = run(jnp.int32(layer))
+    want = ref.moe_held_experts(x, idx, w, wg[layer], wu[layer], wd[layer],
+                                lo)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4,
+                               atol=1e-5)
+    assert int(n_held) > 0
+
+
+@pytest.mark.parametrize("tiles", [[0, 2, 0, 0, 1, 0], [1, 0, 0, 3, 0, 0],
+                                   [0, 0, 0, 0, 0, 4], [1, 1, 1, 1, 1, 1]])
+def test_moe_held_experts_reads_only_chosen_experts(tiles):
+    """The weight block of each grid step, in grid order: a new block (a
+    fetch) only ever belongs to an expert some row chose, and each chosen
+    expert's tiles are each fetched once."""
+    from repro.kernels.moe_held import weight_blocks
+
+    nj = 3
+    we, act, jfix = (np.asarray(a) for a in
+                     weight_blocks(jnp.asarray(tiles, jnp.int32), nj))
+    blocks = [(int(we[e]), int(j * act[e] + jfix[e]))
+              for e in range(len(tiles)) for j in range(nj)]
+    fetched = [b for i, b in enumerate(blocks) if i == 0 or b != blocks[i - 1]]
+    chosen = [e for e, n in enumerate(tiles) if n]
+    assert fetched == [(e, j) for e in chosen for j in range(nj)]
