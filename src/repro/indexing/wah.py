@@ -39,6 +39,8 @@ _COUNT_MASK = (1 << 30) - 1
 def build_wah_index(values: jax.Array, cardinality: int, impl: str = "auto"):
     """Build a WAH bitmap index of ``values`` (uint32 < cardinality).
 
+    Every value must lie below ``cardinality``: the sort orders only the
+    bits that ``cardinality - 1`` needs, and step (6) drops larger values.
     Returns ``(index_words, n_words, starts, counts)``: the compacted word
     stream, its logical length, and the per-value lookup table. ``impl``
     picks the sort/interleave/compaction kernels as in
@@ -50,7 +52,9 @@ def build_wah_index(values: jax.Array, cardinality: int, impl: str = "auto"):
 
     # (1)+(2): encode with position, stable sort by value → positions stay
     # ascending within each value, hence chunk ids are ascending.
-    v_sorted, pos_sorted = ops.radix_sort(values, pos, impl=impl)
+    key_bits = max(1, (cardinality - 1).bit_length())
+    v_sorted, pos_sorted = ops.radix_sort(values, pos, key_bits=key_bits,
+                                          impl=impl)
     v_sorted = v_sorted.astype(jnp.int32)
 
     # (3): 31-bit chunk literals by segmented OR (distinct bits → sum).

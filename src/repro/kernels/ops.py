@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from .ref import radix_passes
 from .flash_attention import pallas_flash_attention
 from .mandelbrot import pallas_mandelbrot
 from .matmul import pallas_matmul
@@ -29,7 +30,8 @@ from .stream_compact import pallas_local_compact
 from .wah import pallas_wah_interleave
 
 __all__ = ["matmul", "mandelbrot", "stream_compact", "radix_sort",
-           "wah_interleave", "flash_attention", "moe_held_experts", "on_tpu"]
+           "radix_passes", "wah_interleave", "flash_attention",
+           "moe_held_experts", "on_tpu"]
 
 
 def on_tpu() -> bool:
@@ -115,37 +117,48 @@ def _place_blocks(blocks, counts):
 
 
 # ----------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("bits_per_pass", "bs", "impl"))
-def radix_sort(keys, values=None, *, bits_per_pass: int = 8, bs: int = 256,
-               impl: str = "auto"):
-    """Stable LSD radix sort of uint32 keys (+ optional payload)."""
+@functools.partial(jax.jit,
+                   static_argnames=("bits_per_pass", "key_bits", "bs", "impl"))
+def radix_sort(keys, values=None, *, bits_per_pass: int = 8,
+               key_bits: int = 32, bs: int = 256, impl: str = "auto"):
+    """Stable LSD radix sort of uint32 keys (+ optional payload).
+
+    Sorts by the low ``key_bits`` bits in ``radix_passes(key_bits,
+    bits_per_pass)`` digit passes, so keys must lie below ``2**key_bits``:
+    a pass over higher digits that are zero everywhere would only copy.
+    """
     use, interp = _use_pallas(impl)
     n = keys.shape[0]
     if not use or n % bs or bits_per_pass > 8:
-        return ref.radix_sort_u32(keys, values, bits_per_pass=bits_per_pass)
+        return ref.radix_sort_u32(keys, values, bits_per_pass=bits_per_pass,
+                                  key_bits=key_bits)
     k = keys.astype(jnp.uint32)
-    idx = jnp.arange(n, dtype=jnp.int32)
-    nb, nbins = n // bs, 1 << bits_per_pass
-    for p in range(32 // bits_per_pass):
-        shift = p * bits_per_pass
-        hist, rank = pallas_radix_pass(k, bs=bs, bits=bits_per_pass,
-                                       shift=shift, interpret=interp)
-        # global base per digit (exclusive over bins, summed over blocks)
-        total = jnp.sum(hist, axis=0)                          # (nbins,)
-        gbase = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                 jnp.cumsum(total)[:-1]])      # (nbins,)
-        # per-(block, digit) offset: exclusive cumsum over blocks
-        bprefix = jnp.concatenate(
-            [jnp.zeros((1, nbins), jnp.int32),
-             jnp.cumsum(hist, axis=0)[:-1]], axis=0)           # (nb, nbins)
-        digit = ((k >> jnp.uint32(shift)) & jnp.uint32(nbins - 1)).astype(jnp.int32)
-        blk = jnp.arange(n, dtype=jnp.int32) // bs
-        dest = gbase[digit] + bprefix[blk, digit] + rank.reshape(-1)
-        k = jnp.zeros_like(k).at[dest].set(k)
-        idx = jnp.zeros_like(idx).at[dest].set(idx)
+    nbins = 1 << bits_per_pass
+    blk = jnp.arange(n, dtype=jnp.int32) // bs
+    for p in range(radix_passes(key_bits, bits_per_pass)):
+        with jax.named_scope(f"radix_pass{p}"):
+            shift = p * bits_per_pass
+            hist, rank = pallas_radix_pass(k, bs=bs, bits=bits_per_pass,
+                                           shift=shift, interpret=interp)
+            # global base per digit (exclusive over bins, summed over blocks)
+            total = jnp.sum(hist, axis=0)                      # (nbins,)
+            gbase = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                     jnp.cumsum(total)[:-1]])  # (nbins,)
+            # per-(block, digit) offset: exclusive cumsum over blocks
+            bprefix = jnp.concatenate(
+                [jnp.zeros((1, nbins), jnp.int32),
+                 jnp.cumsum(hist, axis=0)[:-1]], axis=0)       # (nb, nbins)
+            digit = ((k >> jnp.uint32(shift))
+                     & jnp.uint32(nbins - 1)).astype(jnp.int32)
+            dest = gbase[digit] + bprefix[blk, digit] + rank.reshape(-1)
+            # ``dest`` is a permutation of 0..n-1; the payload rides along
+            k = jnp.zeros_like(k).at[dest].set(k, unique_indices=True)
+            if values is not None:
+                values = jnp.zeros_like(values).at[dest].set(
+                    values, unique_indices=True)
     if values is None:
         return k
-    return k, jnp.take(values, idx)
+    return k, values
 
 
 # ----------------------------------------------------------------------------

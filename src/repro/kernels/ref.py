@@ -16,6 +16,7 @@ __all__ = [
     "matmul",
     "mandelbrot",
     "stream_compact",
+    "radix_passes",
     "radix_sort_u32",
     "wah_interleave",
     "flash_attention",
@@ -81,17 +82,25 @@ def stream_compact(x: jax.Array, drop_value: int = 0) -> Tuple[jax.Array, jax.Ar
 # ----------------------------------------------------------------------------
 # paper §4 — LSD radix sort (fixed digit cardinality, paper uses 16 bits)
 # ----------------------------------------------------------------------------
+def radix_passes(key_bits: int, bits_per_pass: int) -> int:
+    """Digit passes an LSD radix sort makes over keys of ``key_bits`` bits."""
+    if not 1 <= key_bits <= 32:
+        raise ValueError(f"key_bits={key_bits} not in 1..32")
+    return -(-key_bits // bits_per_pass)
+
+
 def radix_sort_u32(keys: jax.Array, values: Optional[jax.Array] = None,
-                   bits_per_pass: int = 16):
+                   bits_per_pass: int = 16, key_bits: int = 32):
     """Stable LSD radix sort of uint32 keys (optionally with a payload).
 
     Matches the paper's "radix sort using a fixed cardinality of 16 bits".
     The oracle uses jnp.argsort per digit pass to mirror pass structure.
+    Only the low ``key_bits`` bits are sorted, so keys must lie below
+    ``2**key_bits``.
     """
-    assert 32 % bits_per_pass == 0
     k = keys.astype(jnp.uint32)
     idx = jnp.arange(k.shape[0])
-    for p in range(32 // bits_per_pass):
+    for p in range(radix_passes(key_bits, bits_per_pass)):
         digit = (k >> (p * bits_per_pass)) & ((1 << bits_per_pass) - 1)
         order = jnp.argsort(digit.astype(jnp.int32), stable=True)
         k = k[order]

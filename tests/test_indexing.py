@@ -51,6 +51,29 @@ def test_wah_matches_numpy_reference_word_count():
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("card", [1, 256, 257])
+def test_wah_matches_numpy_reference_across_digit_widths(card):
+    """The sort orders only the bits ``card - 1`` needs: one 8-bit digit at
+    256 values, two at 257. Words, count and table match the sequential
+    CPU reference, and the oracle path (``impl="ref"``) gives the same
+    index."""
+    rng = np.random.default_rng(card)
+    values = rng.integers(0, card, 4096).astype(np.uint32)
+    values[-1] = card - 1          # the widest key is always present
+    got = build_wah_index(jnp.asarray(values), card)
+    words, n_words, starts, counts = (np.asarray(x) for x in got)
+    ref_words, ref_n, ref_starts, ref_counts = build_wah_index_numpy(values,
+                                                                     card)
+    assert int(n_words) == ref_n
+    np.testing.assert_array_equal(words[:ref_n], ref_words)
+    assert not words[ref_n:].any()
+    np.testing.assert_array_equal(starts, ref_starts)
+    np.testing.assert_array_equal(counts, ref_counts)
+    oracle = build_wah_index(jnp.asarray(values), card, impl="ref")
+    for a, b in zip(got, oracle):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_wah_compresses_sparse_data():
     """A rare value's bitmap must be ≪ the dense bitmap size."""
     values = np.zeros(31 * 1000, np.uint32)

@@ -177,6 +177,34 @@ def test_radix_sort_16bit_oracle_path():
     np.testing.assert_array_equal(np.asarray(kp), np.sort(keys))
 
 
+@pytest.mark.parametrize("impl", ["pallas", "ref"])
+@pytest.mark.parametrize("bits", [4, 8])
+@pytest.mark.parametrize("key_bits", [1, 5, 8, 12, 16])
+def test_radix_sort_key_bits(key_bits, bits, impl):
+    """Keys below ``2**key_bits`` sort fully in the passes that width needs,
+    stably: the payload is the stable argsort."""
+    keys = RNG.integers(0, 2**key_bits, 512).astype(np.uint32)
+    vals = np.arange(keys.size, dtype=np.int32)
+    kp, vp = ops.radix_sort(jnp.asarray(keys), jnp.asarray(vals),
+                            bits_per_pass=bits, key_bits=key_bits, impl=impl)
+    np.testing.assert_array_equal(np.asarray(kp), np.sort(keys))
+    np.testing.assert_array_equal(np.asarray(vp),
+                                  np.argsort(keys, kind="stable"))
+
+
+@pytest.mark.parametrize("key_bits,bits,passes",
+                         [(8, 8, 1), (9, 8, 2), (16, 8, 2), (32, 8, 4),
+                          (1, 8, 1), (5, 4, 2), (32, 4, 8)])
+def test_radix_passes(key_bits, bits, passes):
+    assert ops.radix_passes(key_bits, bits) == passes
+
+
+@pytest.mark.parametrize("key_bits", [0, 33])
+def test_radix_passes_rejects_widths_outside_a_u32(key_bits):
+    with pytest.raises(ValueError):
+        ops.radix_passes(key_bits, 8)
+
+
 # ----------------------------------------------------------------------------
 @pytest.mark.parametrize("n,bs", [(512, 512), (2048, 512), (1024, 256)])
 def test_wah_interleave_sweep(n, bs):

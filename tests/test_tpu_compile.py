@@ -167,3 +167,23 @@ def test_moonlight_share_decode_step_fits_one_v5e(one_chip, monkeypatch):
     ma = compiled.memory_analysis()
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert 10.3e9 < ma.argument_size_in_bytes and used < HBM_BYTES, used
+
+
+def test_wah_build_sorts_in_one_digit_pass_on_v5e(one_chip, monkeypatch):
+    """``build_wah_index`` over 2**24 values of cardinality 256, as the WAH
+    cell runs it: keys of 8 bits take one 8-bit radix pass, not four, and
+    the whole build fits the chip."""
+    from repro.indexing import build_wah_index
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    values = _spec(one_chip, (1 << 24,), jnp.uint32)
+    compiled = jax.jit(functools.partial(build_wah_index, cardinality=256)
+                       ).lower(values).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    passes = [c for c in calls if "pallas_radix_pass" in c]
+    assert len(passes) == 1, passes
+    ma = compiled.memory_analysis()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert 0 < used < HBM_BYTES, used
