@@ -1,6 +1,6 @@
 """The paper's contribution: OpenCL-style kernel actors for JAX/TPU.
 
-The v2 surface is declarative — signature and index space are captured at
+The surface is declarative — signature and index space are captured at
 definition site, composition is a builder, pooling is one call:
 
     from repro.core import ActorSystem, NDRange, In, Out, dim_vec, kernel
@@ -22,20 +22,16 @@ Non-linear compositions use the typed DAG builder (``repro.core.Graph``):
 nodes are kernels/actors/Python stages, edges are shape/dtype-checked
 ports, and ``build()`` validates the topology before spawning — see the
 README "Dataflow graphs" section and ``examples/graph_diamond.py``.
-
-The v1 positional surface (``mngr.spawn(fn, name, nd_range, *specs)``,
-``compose``, ``fuse``) remains available as deprecated shims.
 """
 from .actor import Actor, ActorRef, ActorSystem, Message
 from .api import ActorPool, KernelDecl, Pipeline, kernel
-from .compose import ComposedActor, compose, fuse
 from .errors import (AccessViolation, ActorError, ActorFailed,
                      ArityMismatchError, DanglingPortError, DeadlineExceeded,
                      DownMessage, ExitMessage, GraphCycleError, GraphError,
                      MailboxClosed, PortTypeMismatchError, SignatureMismatch)
 from .facade import KernelActor
 from .graph import Graph, GraphNode, GraphPlan, GraphRef, Port, PortType
-from .manager import Device, DeviceManager, Platform, Program
+from .manager import Device, DeviceManager, Platform
 from .memref import (DeviceRef, RefRegistry, as_device_array, live_ref_count,
                      memory_stats, payload_nbytes, reset_transfer_stats,
                      transfer_count, tree_release, tree_unwrap, tree_wrap)
@@ -49,14 +45,13 @@ from .signature import In, InOut, KernelSignature, Local, NDRange, Out, Priv, di
 __all__ = [
     "Actor", "ActorRef", "ActorSystem", "Message",
     "ActorPool", "KernelDecl", "Pipeline", "kernel",
-    "ComposedActor", "compose", "fuse",
     "AccessViolation", "ActorError", "ActorFailed", "ArityMismatchError",
     "DanglingPortError", "DeadlineExceeded", "DownMessage", "ExitMessage",
     "GraphCycleError", "GraphError", "MailboxClosed",
     "PortTypeMismatchError", "SignatureMismatch",
     "KernelActor",
     "Graph", "GraphNode", "GraphPlan", "GraphRef", "Port", "PortType",
-    "Device", "DeviceManager", "Platform", "Program",
+    "Device", "DeviceManager", "Platform",
     "DeviceRef", "RefRegistry", "as_device_array", "live_ref_count",
     "memory_stats", "reset_transfer_stats", "transfer_count",
     "tree_release", "tree_unwrap", "tree_wrap",

@@ -1,9 +1,5 @@
-"""Declarative kernel-actor API (v2) — the unified surface.
-
-The v1 surface scattered kernel declaration, composition, placement, and
-pooling across four call conventions (``DeviceManager.spawn`` with
-positional specs, ``ActorRef.__mul__``, the free function ``fuse``, and
-``ChunkScheduler``). v2 collapses them into three declarative objects:
+"""Declarative kernel-actor API — the one surface for declaring,
+composing and pooling kernel actors, in three declarative objects:
 
 * :func:`kernel` — capture the signature and ND-range **at definition
   site**::
@@ -31,9 +27,6 @@ positional specs, ``ActorRef.__mul__``, the free function ``fuse``, and
 * :class:`ActorPool` / ``DeviceManager.spawn_pool`` — N replicas behind
   one ref, routed round-robin or by load (outstanding requests + device
   queue depth); pools plug directly into :class:`ChunkScheduler`.
-
-The v1 functions (``compose``, ``fuse``, positional ``spawn``) remain as
-thin shims over this module.
 """
 from __future__ import annotations
 
@@ -184,12 +177,6 @@ class Pipeline:
         actor = st.actor if st else None
         return actor if isinstance(actor, KernelActor) else None
 
-    def _composed_stages_of(self, ref: ActorRef):
-        from .compose import ComposedActor
-        st = self.system._actors.get(ref.actor_id)
-        actor = st.actor if st else None
-        return list(actor.stages) if isinstance(actor, ComposedActor) else None
-
     def resolved_mode(self) -> str:
         """The mode ``build`` will use (resolves ``auto``)."""
         if self.mode != "auto":
@@ -225,8 +212,8 @@ class Pipeline:
         return self._build_fused()
 
     def _graph_stages_of(self, ref: ActorRef):
-        """The underlying stage refs of a Graph-backed linear pipe (the
-        Graph analogue of :meth:`_composed_stages_of` inlining)."""
+        """The underlying stage refs of a Graph-backed linear pipe, which
+        :meth:`_build_staged` inlines as stages of its own."""
         from .graph import GraphRef
         if isinstance(ref, GraphRef) and ref.plan.chain_refs:
             return list(ref.plan.chain_refs)
@@ -238,7 +225,7 @@ class Pipeline:
 
         Pipeline is the thin linear wrapper over the DAG builder: each
         stage becomes a chain node joined by untyped splat edges (the
-        whole payload tuple flows per hop, exactly the v1 semantics), and
+        whole payload tuple flows per hop), and
         the Graph lowering decides ref emission — an intermediate kernel
         stage is spawned (or cloned, never mutated) with ``emit="ref"``
         whenever its successor can unwrap a
@@ -248,15 +235,13 @@ class Pipeline:
         """
         from .graph import Graph
         mngr = self.system.opencl_manager()
-        # flatten to (kind, target, device), inlining pre-composed chains
-        # (v1 ComposedActor refs and Graph-backed linear pipes alike)
+        # flatten to (kind, target, device), inlining Graph-backed linear pipes
         entries: List[tuple] = []
         for s in self._stages:
             if isinstance(s.target, KernelDecl):
                 entries.append(("decl", s.target, s.device or self.device))
             elif isinstance(s.target, ActorRef):
-                inner = (self._composed_stages_of(s.target)
-                         or self._graph_stages_of(s.target))
+                inner = self._graph_stages_of(s.target)
                 for r in (inner if inner else [s.target]):
                     entries.append(("ref", r, None))
             else:

@@ -42,7 +42,7 @@ import numpy as np
 
 from .actor import Actor
 from .errors import AccessViolation, SignatureMismatch
-from .manager import Device, Program
+from .manager import Device
 from .memref import DeviceRef, as_device_array, registry
 from .signature import In, InOut, KernelSignature, Local, NDRange, Out
 
@@ -94,7 +94,6 @@ class KernelActor(Actor):
 
     def __init__(self, fn: Callable, name: str, nd_range: Optional[NDRange],
                  specs: Sequence, device: Device,
-                 program: Optional[Program] = None,
                  preprocess: Optional[Callable] = None,
                  postprocess: Optional[Callable] = None,
                  donate: bool = True, emit: str = "declared",
@@ -111,7 +110,6 @@ class KernelActor(Actor):
         self.nd_range = nd_range
         self.signature = KernelSignature(*specs)
         self.device = device
-        self.program = program
         self.preprocess = preprocess
         self.postprocess = postprocess
         self.donate = donate
@@ -139,14 +137,7 @@ class KernelActor(Actor):
             return out if isinstance(out, tuple) else (out,)
 
         donate = sig.donate_argnums if self.donate else ()
-        jitted = jax.jit(wrapped, donate_argnums=donate)
-
-        def build():
-            return jitted
-        key = ("jit", self.kernel_name, bool(donate))
-        if self.program is not None:
-            return self.program.compiled(key, build)
-        return jitted
+        return jax.jit(wrapped, donate_argnums=donate)
 
     def on_start(self):
         if self._jitted is None:
@@ -245,7 +236,7 @@ class KernelActor(Actor):
         return KernelActor(fn=self.fn, name=self.kernel_name,
                            nd_range=self.nd_range,
                            specs=self.signature.specs, device=self.device,
-                           program=self.program, preprocess=self.preprocess,
+                           preprocess=self.preprocess,
                            postprocess=self.postprocess, donate=self.donate,
                            emit=emit or self.emit,
                            fused_from=self.fused_from)

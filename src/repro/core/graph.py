@@ -364,14 +364,13 @@ class Graph:
         and concatenate the results on device.
 
         Each chunk pays a fixed dispatch constant (a mailbox hop, a
-        device-side slice, a scheduler round-trip — BENCH_PR5 puts the hop
-        alone near 300 µs), so chunking only wins once per-chunk compute
-        dwarfs it. ``min_chunk_bytes`` (default 1 MiB) caps the effective
-        chunk count so no slice drops below that size: small inputs
-        degrade gracefully to a single whole-array dispatch instead of
-        paying ``chunks`` dispatch constants for sub-millisecond kernels
-        (the BENCH_PR4 ``diamond_graph_mapped`` regression). Pass
-        ``min_chunk_bytes=0`` to force the requested chunk count."""
+        device-side slice, a scheduler round-trip), so chunking only wins
+        once per-chunk compute dwarfs it. ``min_chunk_bytes`` (default 1
+        MiB) caps the effective chunk count so no slice drops below that
+        size: small inputs degrade gracefully to a single whole-array
+        dispatch instead of paying ``chunks`` dispatch constants for
+        sub-millisecond kernels. Pass ``min_chunk_bytes=0`` to force the
+        requested chunk count."""
         if not isinstance(target, KernelDecl):
             raise GraphError(
                 f"{self.name}/{name or _target_name(target)}: map_over "
@@ -812,7 +811,6 @@ class Graph:
             name="fused[" + "+".join(n.name for n in region) + "]",
             nd_range=first_nd, specs=specs,
             device=device if device is not None else mngr.find_device(),
-            program=None,
             preprocess=(head.target.preprocess if head.kind == "kernel"
                         else None),
             postprocess=(tail.target.postprocess if tail.kind == "kernel"
@@ -1078,11 +1076,9 @@ class GraphPlan:
 
     def _linear_chain(self) -> Optional[List[ActorRef]]:
         """The underlying stage refs when this graph is a pure linear
-        chain — lets an outer ``Pipeline`` inline a built pipe's stages
-        (the pre-composed-chain flattening the v1 builder did for
-        :class:`~repro.core.compose.ComposedActor`). Fused interiors carry
-        no ref of their own; the region's single fused actor stands in as
-        one chain stage."""
+        chain — lets an outer ``Pipeline`` inline a built pipe's stages.
+        Fused interiors carry no ref of their own; the region's single
+        fused actor stands in as one chain stage."""
         if len(self.sources) != 1 or len(self.outputs) != 1:
             return None
         if any(n.kind not in ("source",) + _ACTOR_KINDS or n.n_out != 1
@@ -1200,8 +1196,7 @@ class _GraphRun:
     continues the traversal. Every :class:`DeviceRef` produced inside the
     run is registered and — once the run has settled (result delivered and
     all in-flight node futures done) — released, unless it escaped into
-    the final result or came in with the caller's payload. This is the DAG
-    generalization of ``ComposedActor``'s chain ownership: a graph run
+    the final result or came in with the caller's payload, so a graph run
     leaves no live intermediate refs behind, on success *or* failure.
     """
 

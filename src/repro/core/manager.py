@@ -1,28 +1,24 @@
-"""Device discovery and program bookkeeping (paper Fig. 2: manager /
-platform / device / program).
+"""Device discovery and kernel-actor spawning (paper Fig. 2: manager /
+platform / device).
 
 * ``Platform`` wraps a JAX backend (the analogue of an OpenCL platform —
   an entry point provided by a driver).
 * ``Device`` wraps a ``jax.Device`` and tracks an outstanding-dispatch
   counter, the analogue of the per-device command queue.
-* ``Program`` maps kernel names to compiled callables. OpenCL compiles C
-  source at runtime; the JAX analogue is trace-and-compile at first use,
-  with the lowered/compiled executable cached per (name, shapes, device).
 * ``DeviceManager`` is the ``actor_system`` module that "performs platform
   discovery lazily on first access and offers an interface to spawn OpenCL
   actors" (paper §3.2).
 """
 from __future__ import annotations
 
-import warnings
-from typing import Any, Callable, Dict, Optional, Sequence
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 
 from ..analysis.runtime import make_lock
 from .signature import NDRange
 
-__all__ = ["Platform", "Device", "Program", "DeviceManager"]
+__all__ = ["Platform", "Device", "DeviceManager"]
 
 
 class Device:
@@ -84,36 +80,6 @@ class Platform:
 
     def __repr__(self):
         return f"Platform({self.name}, {len(self.devices)} devices)"
-
-
-class Program:
-    """Named kernels + per-shape compiled-executable cache.
-
-    ``kernels`` maps a kernel name to a traceable callable. ``retrieve``
-    mirrors ``clCreateKernel``-by-name; ``compiled`` caches executables the
-    way OpenCL caches ``cl_program`` binaries per device.
-    """
-
-    def __init__(self, kernels: Dict[str, Callable], device: Optional[Device] = None,
-                 options: Optional[Dict[str, Any]] = None):
-        self.kernels = dict(kernels)
-        self.device = device
-        self.options = dict(options or {})
-        self._cache: Dict[Any, Any] = {}
-        self._lock = make_lock("Program")
-
-    def retrieve(self, name: str) -> Callable:
-        try:
-            return self.kernels[name]
-        except KeyError:
-            raise KeyError(f"program has no kernel named {name!r}; "
-                           f"available: {sorted(self.kernels)}") from None
-
-    def compiled(self, key: Any, build: Callable[[], Any]) -> Any:
-        with self._lock:
-            if key not in self._cache:
-                self._cache[key] = build()
-            return self._cache[key]
 
 
 class DeviceManager:
@@ -180,71 +146,46 @@ class DeviceManager:
         return placement_service().pick_device(self.devices(),
                                                context=context).chosen
 
-    # -- program / actor creation -------------------------------------------
-    def create_program(self, kernels: Dict[str, Callable],
-                       device: Optional[Device] = None, **options) -> Program:
-        return Program(kernels, device or self.find_device(), options)
-
+    # -- actor creation -------------------------------------------------
     def spawn(self, source, name: Optional[str] = None,
               nd_range: Optional[NDRange] = None, *specs, **kwargs):
         """Spawn an OpenCL actor (paper Listing 2/3/5).
 
-        v2 form: ``source`` is a :func:`repro.core.kernel`-decorated
-        callable (a :class:`~repro.core.api.KernelDecl`) that already
-        carries its signature and ND-range; ``name``/``nd_range`` and a
-        ``device=`` keyword act as per-spawn overrides.
-
-        v1 form (deprecated shim, kept so existing callers don't break):
-        ``source`` is a traceable callable (the JAX stand-in for OpenCL C
-        source) or a :class:`Program` plus positional ``name``,
-        ``nd_range``, and ``*specs``. Optional ``preprocess``/
-        ``postprocess`` keyword arguments mirror the paper's conversion
-        functions in both forms.
+        ``source`` is a :func:`repro.core.kernel`-decorated callable (a
+        :class:`~repro.core.api.KernelDecl`) that already carries its
+        signature and ND-range; ``name``/``nd_range``/``*specs`` and the
+        ``preprocess``/``postprocess``/``donate`` keywords act as
+        per-spawn overrides of the declaration, ``device=`` places the
+        actor, and ``emit``/``lazy_init`` are passed to the actor.
         """
         from .api import KernelDecl     # local import: avoid cycle
         from .facade import KernelActor
-        if isinstance(source, KernelDecl):
-            decl = source
-            overrides = {}
-            if name is not None:
-                overrides["name"] = name
-            if nd_range is not None:
-                overrides["nd_range"] = nd_range
-            if specs:
-                overrides["specs"] = specs
-            for opt in ("preprocess", "postprocess", "donate"):
-                if opt in kwargs:
-                    overrides[opt] = kwargs.pop(opt)
-            if overrides:
-                decl = decl.with_options(**overrides)
-            device = kwargs.pop("device", None) or self.find_device()
-            lazy_init = kwargs.pop("lazy_init", True)
-            emit = kwargs.pop("emit", "declared")
-            if kwargs:
-                raise TypeError(f"unknown spawn options: {sorted(kwargs)}")
-            actor = KernelActor(fn=decl.fn, name=decl.name,
-                                nd_range=decl.nd_range, specs=decl.specs,
-                                device=device, program=None,
-                                preprocess=decl.preprocess,
-                                postprocess=decl.postprocess,
-                                donate=decl.donate, emit=emit)
-            return self.system.spawn(actor, lazy_init=lazy_init)
-        warnings.warn(
-            "positional DeviceManager.spawn(source, name, nd_range, *specs) "
-            "is deprecated; declare kernels with @repro.core.kernel",
-            PendingDeprecationWarning, stacklevel=2)
-        if isinstance(source, Program):
-            program, fn = source, source.retrieve(name)
-            device = kwargs.pop("device", None) or program.device or self.find_device()
-        else:
-            if not callable(source):
-                raise TypeError("source must be a callable or Program")
-            program, fn = None, source
-            device = kwargs.pop("device", None) or self.find_device()
-        actor = KernelActor(fn=fn, name=name or getattr(fn, "__name__", "kernel"),
-                            nd_range=nd_range, specs=specs, device=device,
-                            program=program, **kwargs)
-        return self.system.spawn(actor)
+        if not isinstance(source, KernelDecl):
+            raise TypeError(
+                f"cannot spawn {source!r} as a kernel actor: declare it "
+                "with @repro.core.kernel(In(...), Out(...), nd_range=...)")
+        overrides = {}
+        if name is not None:
+            overrides["name"] = name
+        if nd_range is not None:
+            overrides["nd_range"] = nd_range
+        if specs:
+            overrides["specs"] = specs
+        for opt in ("preprocess", "postprocess", "donate"):
+            if opt in kwargs:
+                overrides[opt] = kwargs.pop(opt)
+        decl = source.with_options(**overrides) if overrides else source
+        device = kwargs.pop("device", None) or self.find_device()
+        lazy_init = kwargs.pop("lazy_init", True)
+        emit = kwargs.pop("emit", "declared")
+        if kwargs:
+            raise TypeError(f"unknown spawn options: {sorted(kwargs)}")
+        actor = KernelActor(fn=decl.fn, name=decl.name,
+                            nd_range=decl.nd_range, specs=decl.specs,
+                            device=device, preprocess=decl.preprocess,
+                            postprocess=decl.postprocess,
+                            donate=decl.donate, emit=emit)
+        return self.system.spawn(actor, lazy_init=lazy_init)
 
     def spawn_pool(self, source, n: int, *, policy: str = "round_robin",
                    devices: Optional[Sequence[Device]] = None,

@@ -13,9 +13,9 @@ This module unifies them behind a single process-wide
   (read straight from :class:`~repro.core.memref.RefRegistry` through the
   :class:`~repro.core.manager.Device` wrappers),
 * the **wire cost source** — a :class:`WireCostModel` of per-hop latency
-  and bytes-on-wire for raw vs int8 transfers, seeded from BENCH_PR5's
-  measured numbers and refined online from observed ``repro.net``
-  round-trips (:meth:`PlacementService.observe_hop`), and
+  and bytes-on-wire for raw vs int8 transfers, starting from untuned
+  defaults and refined online from observed ``repro.net`` round-trips
+  (:meth:`PlacementService.observe_hop`), and
 * the **replica cost source** — mesh load snapshots fed in through
   :meth:`PlacementService.observe_replica`.
 
@@ -35,7 +35,6 @@ service lock is held.
 from __future__ import annotations
 
 import dataclasses
-import json
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -57,10 +56,9 @@ class WireCostModel:
     A hop costs ``latency + wire_bytes / throughput``; int8 compression
     shrinks ``wire_bytes`` by :attr:`int8_ratio` at the price of a
     quantize/dequantize pass (:attr:`compress_overhead_s` plus a
-    throughput term). The defaults are seeded from the BENCH_PR5
-    measurements (localhost socket pair, in-process nodes): the n=1024
-    round trip pins the base latency, the n=262144 one the throughput,
-    and the measured ``wire_raw/wire_int8`` ratio converges on 4.0.
+    throughput term). The defaults are untuned starting points, not
+    measurements of any host pair, that :meth:`observe` refines;
+    ``int8_ratio`` 4.0 is four float32 bytes to one int8 byte.
 
     :meth:`observe` refines the estimate online from real transfer
     timings — small payloads update the latency EWMA, large ones the
@@ -93,35 +91,6 @@ class WireCostModel:
         #: peer -> [latency_s, bytes_per_s] learned from observations
         self._peer: Dict[str, List[float]] = {}
         self.observations = 0
-
-    # -- seeding -----------------------------------------------------------
-    @classmethod
-    def from_bench(cls, data, **overrides) -> "WireCostModel":
-        """Seed a model from a BENCH_PR5-style snapshot: a dict (or path
-        to a JSON file) whose ``"sizes"`` section maps ``n<N>`` entries to
-        ``remote_hop_us`` / ``wire_raw_bytes`` / ``wire_int8_bytes`` /
-        ``compression_ratio``. The smallest size pins latency, the
-        largest pins throughput."""
-        if isinstance(data, (str, bytes)):
-            with open(data, "r", encoding="utf-8") as fh:
-                data = json.load(fh)
-        sizes = data.get("sizes", data)
-        rows = sorted(sizes.values(), key=lambda r: r["wire_raw_bytes"])
-        if not rows:
-            return cls(**overrides)
-        small, big = rows[0], rows[-1]
-        kw: Dict[str, Any] = {}
-        kw["latency_s"] = small["remote_hop_us"] * 1e-6
-        span_s = (big["remote_hop_us"] - small["remote_hop_us"]) * 1e-6
-        span_b = big["wire_raw_bytes"] - small["wire_raw_bytes"]
-        if span_s > 0 and span_b > 0:
-            kw["bytes_per_s"] = span_b / span_s
-        ratios = [r["compression_ratio"] for r in rows
-                  if r.get("compression_ratio")]
-        if ratios:
-            kw["int8_ratio"] = max(ratios)
-        kw.update(overrides)
-        return cls(**kw)
 
     # -- queries -----------------------------------------------------------
     def _params(self, peer: Optional[str]) -> Tuple[float, float]:
@@ -305,7 +274,7 @@ class PlacementService:
     Cost knobs (all injectable for tests):
 
     * ``dispatch_s`` — estimated seconds a queued dispatch ahead of us
-      costs (seeded from BENCH_PR5's ~300 µs local hop).
+      costs (an untuned starting point).
     * ``mem_s_per_byte`` — pressure penalty per live byte on a device: a
       loaded device keeps winning until its watermark, not forever.
     * ``host_bytes_per_s`` — intra-host device-to-device copy throughput,

@@ -13,8 +13,7 @@ requests in flight on the dead replica replay on the survivors, and the
 demo asserts **zero lost and zero duplicated requests** — every
 submitted request resolves exactly once with the tokens the toy model
 predicts. The returned summary records achieved RPS and p99 latency
-before / during / after the failure window; ``benchmarks/bench_mesh.py``
-snapshots it into ``BENCH_PR8.json``.
+before / during / after the failure window.
 
 Everything here is module-level so both sides of the spawn can import it
 (the worker needs :func:`toy_engine` importable to build the shipped

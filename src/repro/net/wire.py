@@ -155,7 +155,7 @@ def decode(data: bytes, *, device=None) -> Any:
 
 def encoded_size(obj: Any, *, compress: bool = False) -> int:
     """Wire bytes ``obj`` would occupy — measured **without** mutating
-    any live ref (benchmarks compare raw vs int8-compressed spills)."""
+    any live ref (to compare raw with int8-compressed spills)."""
     return len(encode(obj, compress=compress, consume=False))
 
 

@@ -1,6 +1,6 @@
-"""Tests for the declarative v2 kernel-actor API: @kernel declaration
-capture, Pipeline staged/fused/auto equivalence, pool routing, and the
-v1 shim compatibility (ISSUE 1 acceptance surface)."""
+"""Tests for the declarative kernel-actor API: @kernel declaration
+capture, spawning only declared kernels, Pipeline staged/fused/auto
+equivalence, and pool routing."""
 import threading
 import time
 
@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.core import (ActorPool, ActorSystem, ChunkScheduler, In, KernelDecl,
-                        NDRange, Out, Pipeline, compose, dim_vec, fuse, kernel)
+                        NDRange, Out, Pipeline, dim_vec, kernel)
 
 
 @pytest.fixture(scope="module")
@@ -85,6 +85,18 @@ def test_spawn_rejects_unknown_kwargs(mngr):
         mngr.spawn(add_one, bogus_option=1)
 
 
+@pytest.mark.parametrize("args", [
+    (lambda x: x + 1.0,),
+    (lambda x: x + 1.0, "add_one", NDRange(dim_vec(N)), In(jnp.float32),
+     Out(jnp.float32)),
+], ids=["bare-callable", "positional-name-ndrange-specs"])
+def test_manager_spawn_rejects_undeclared_kernels(mngr, args):
+    spawned = mngr.system.stats["spawned"]
+    with pytest.raises(TypeError, match=r"@repro\.core\.kernel"):
+        mngr.spawn(*args)
+    assert mngr.system.stats["spawned"] == spawned
+
+
 # ----------------------------------------------------------------------------
 # Pipeline: staged / fused / auto equivalence (acceptance criterion)
 # ----------------------------------------------------------------------------
@@ -142,21 +154,6 @@ def test_pipeline_empty_or_bad_stage_raises(system):
         Pipeline(system).stage(42)
     with pytest.raises(ValueError):
         Pipeline(system, mode="bogus")
-
-
-# ----------------------------------------------------------------------------
-# v1 shims stay equivalent to the v2 builder
-# ----------------------------------------------------------------------------
-def test_v1_shims_match_pipeline(system):
-    a = system.spawn(add_one)
-    d = system.spawn(double)
-    x = np.arange(N, dtype=np.float32)
-    composed = compose(system, a, d)          # staged shim
-    fused = fuse(system, a, d, name="f2")     # fused shim
-    infix = d * a                             # paper's Listing 5 form
-    np.testing.assert_allclose(composed.ask(x), (x + 1) * 2)
-    np.testing.assert_allclose(fused.ask(x), (x + 1) * 2)
-    np.testing.assert_allclose(infix.ask(x), (x + 1) * 2)
 
 
 # ----------------------------------------------------------------------------
@@ -252,15 +249,6 @@ def test_pool_outstanding_consistent_under_hammer(system):
             break
         time.sleep(0.01)
     assert all(c == 0 for c in counts), counts
-
-
-def test_v1_compose_fuse_emit_deprecation_warning(system):
-    a = system.spawn(add_one)
-    d = system.spawn(double)
-    with pytest.warns(DeprecationWarning, match="compose"):
-        compose(system, a, d)
-    with pytest.warns(DeprecationWarning, match="fuse"):
-        fuse(system, a, d, name="dep")
 
 
 def test_pool_survives_dead_worker(system):

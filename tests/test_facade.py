@@ -1,5 +1,6 @@
 """Tests for the kernel-actor facade, mem_refs, composition, scheduler
-(paper §3.2–3.6)."""
+(paper §3.2–3.6), through ``@kernel`` declarations spawned on the device
+manager."""
 import threading
 import time
 
@@ -8,8 +9,8 @@ import numpy as np
 import pytest
 
 from repro.core import (ActorSystem, ChunkScheduler, DeviceRef, In, InOut,
-                        NDRange, Out, SignatureMismatch, compose, dim_vec,
-                        fuse, split_offload)
+                        NDRange, Out, Pipeline, SignatureMismatch, dim_vec,
+                        kernel, split_offload)
 
 
 @pytest.fixture(scope="module")
@@ -30,9 +31,10 @@ def _mm(a, b):
 
 def test_matmul_facade_value_semantics(mngr):
     n = 32
-    w = mngr.spawn(_mm, "m_mult", NDRange(dim_vec(n, n)),
-                   In(jnp.float32), In(jnp.float32),
-                   Out(jnp.float32, shape=(n, n)))
+    w = mngr.spawn(kernel(In(jnp.float32), In(jnp.float32),
+                          Out(jnp.float32, shape=(n, n)),
+                          nd_range=NDRange(dim_vec(n, n)),
+                          name="m_mult")(_mm))
     a = np.random.default_rng(0).random((n, n), np.float32)
     b = np.random.default_rng(1).random((n, n), np.float32)
     r = w.ask(a, b)
@@ -41,8 +43,9 @@ def test_matmul_facade_value_semantics(mngr):
 
 
 def test_out_ref_returns_deviceref(mngr):
-    w = mngr.spawn(lambda x: x * 3.0, "scale", NDRange(dim_vec(8)),
-                   In(jnp.float32), Out(jnp.float32, as_ref=True))
+    w = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32, as_ref=True),
+                          nd_range=NDRange(dim_vec(8)),
+                          name="scale")(lambda x: x * 3.0))
     r = w.ask(np.ones(8, np.float32))
     assert isinstance(r, DeviceRef)
     np.testing.assert_allclose(r.to_value(), 3.0)
@@ -53,18 +56,22 @@ def test_out_ref_returns_deviceref(mngr):
 
 def test_deviceref_not_serializable(mngr):
     import pickle
-    w = mngr.spawn(lambda x: x, "id", NDRange(dim_vec(4)),
-                   In(jnp.float32), Out(jnp.float32, as_ref=True))
+    w = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32, as_ref=True),
+                          nd_range=NDRange(dim_vec(4)),
+                          name="id")(lambda x: x))
     r = w.ask(np.zeros(4, np.float32))
     with pytest.raises(TypeError):
         pickle.dumps(r)
 
 
 def test_inout_consumes_incoming_ref(mngr):
-    producer = mngr.spawn(lambda x: x + 1.0, "p", NDRange(dim_vec(4)),
-                          In(jnp.float32), Out(jnp.float32, as_ref=True))
-    updater = mngr.spawn(lambda x: x * 2.0, "u", NDRange(dim_vec(4)),
-                         InOut(jnp.float32, as_ref=True))
+    producer = mngr.spawn(kernel(In(jnp.float32),
+                                 Out(jnp.float32, as_ref=True),
+                                 nd_range=NDRange(dim_vec(4)),
+                                 name="p")(lambda x: x + 1.0))
+    updater = mngr.spawn(kernel(InOut(jnp.float32, as_ref=True),
+                                nd_range=NDRange(dim_vec(4)),
+                                name="u")(lambda x: x * 2.0))
     ref = producer.ask(np.zeros(4, np.float32))
     out = updater.ask(ref)
     np.testing.assert_allclose(out.to_value(), 2.0)
@@ -74,15 +81,17 @@ def test_inout_consumes_incoming_ref(mngr):
 
 
 def test_dtype_mismatch_raises(mngr):
-    w = mngr.spawn(lambda x: x, "id2", NDRange(dim_vec(4)),
-                   In(jnp.float32), Out(jnp.float32))
+    w = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                          nd_range=NDRange(dim_vec(4)),
+                          name="id2")(lambda x: x))
     with pytest.raises(SignatureMismatch):
         w.ask(np.zeros(4, np.int32))
 
 
 def test_wrong_arity_raises(mngr):
-    w = mngr.spawn(lambda x: x, "id3", NDRange(dim_vec(4)),
-                   In(jnp.float32), Out(jnp.float32))
+    w = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                          nd_range=NDRange(dim_vec(4)),
+                          name="id3")(lambda x: x))
     with pytest.raises(SignatureMismatch):
         w.ask(np.zeros(4, np.float32), np.zeros(4, np.float32))
 
@@ -97,10 +106,10 @@ def test_pre_post_processing(mngr):
         return {"matrix": result}
 
     n = 8
-    w = mngr.spawn(_mm, "mm_pp", NDRange(dim_vec(n, n)),
-                   In(jnp.float32), In(jnp.float32),
-                   Out(jnp.float32, shape=(n, n)),
-                   preprocess=pre, postprocess=post)
+    w = mngr.spawn(kernel(In(jnp.float32), In(jnp.float32),
+                          Out(jnp.float32, shape=(n, n)),
+                          nd_range=NDRange(dim_vec(n, n)), name="mm_pp",
+                          preprocess=pre, postprocess=post)(_mm))
     a = np.eye(n)
     out = w.ask((a, a))
     np.testing.assert_allclose(out["matrix"], a, rtol=1e-6)
@@ -129,34 +138,44 @@ def test_ndrange_split_fractions():
 
 def test_staged_composition_device_resident(mngr, system):
     """Paper §3.5: references flow between stages, data stays on device."""
-    s1 = mngr.spawn(lambda x: x + 1.0, "s1", NDRange(dim_vec(16)),
-                    In(jnp.float32), Out(jnp.float32, as_ref=True))
-    s2 = mngr.spawn(lambda x: x * 2.0, "s2", NDRange(dim_vec(16)),
-                    In(jnp.float32), Out(jnp.float32, as_ref=True))
-    s3 = mngr.spawn(lambda x: x - 3.0, "s3", NDRange(dim_vec(16)),
-                    In(jnp.float32), Out(jnp.float32))
+    s1 = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32, as_ref=True),
+                           nd_range=NDRange(dim_vec(16)),
+                           name="s1")(lambda x: x + 1.0))
+    s2 = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32, as_ref=True),
+                           nd_range=NDRange(dim_vec(16)),
+                           name="s2")(lambda x: x * 2.0))
+    s3 = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                           nd_range=NDRange(dim_vec(16)),
+                           name="s3")(lambda x: x - 3.0))
     pipe = s3 * s2 * s1  # s3(s2(s1(x)))
     x = np.arange(16, dtype=np.float32)
     np.testing.assert_allclose(pipe.ask(x), (x + 1) * 2 - 3)
 
 
 def test_fused_composition_single_program(mngr, system):
-    s1 = mngr.spawn(lambda x: x + 1.0, "f1", NDRange(dim_vec(16)),
-                    In(jnp.float32), Out(jnp.float32, as_ref=True))
-    s2 = mngr.spawn(lambda x: x * 2.0, "f2", NDRange(dim_vec(16)),
-                    In(jnp.float32), Out(jnp.float32))
-    fused = fuse(system, s1, s2, name="f12")
+    s1 = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32, as_ref=True),
+                           nd_range=NDRange(dim_vec(16)),
+                           name="f1")(lambda x: x + 1.0))
+    s2 = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                           nd_range=NDRange(dim_vec(16)),
+                           name="f2")(lambda x: x * 2.0))
+    fused = Pipeline(system, mode="fused", name="f12").stages([s1, s2]).build()
+    assert fused.plan.fused_regions == [["f12/f1", "f12/f2"]]
+    assert len(fused.node_refs) == 1
     x = np.arange(16, dtype=np.float32)
     np.testing.assert_allclose(fused.ask(x), (x + 1) * 2)
 
 
 def test_fuse_with_adapter(mngr, system):
-    a = mngr.spawn(lambda x: (x, x + 1.0), "a", NDRange(dim_vec(4)),
-                   In(jnp.float32), Out(jnp.float32, as_ref=True),
-                   Out(jnp.float32, as_ref=True))
-    b = mngr.spawn(lambda x: x * 10.0, "b", NDRange(dim_vec(4)),
-                   In(jnp.float32), Out(jnp.float32))
-    fused = fuse(system, a, lambda x, y: x + y, b, name="ab")
+    a = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32, as_ref=True),
+                          Out(jnp.float32, as_ref=True),
+                          nd_range=NDRange(dim_vec(4)),
+                          name="a")(lambda x: (x, x + 1.0)))
+    b = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                          nd_range=NDRange(dim_vec(4)),
+                          name="b")(lambda x: x * 10.0))
+    fused = (Pipeline(system, mode="fused", name="ab")
+             .stages([a, lambda x, y: x + y, b]).build())
     x = np.ones(4, np.float32)
     np.testing.assert_allclose(fused.ask(x), 30.0)
 
@@ -166,10 +185,10 @@ def test_split_offload_sweep(mngr):
     def work(x):
         return x * x
 
-    w1 = mngr.spawn(work, "w1", NDRange(dim_vec(64)),
-                    In(jnp.float32), Out(jnp.float32))
-    w2 = mngr.spawn(work, "w2", NDRange(dim_vec(64)),
-                    In(jnp.float32), Out(jnp.float32))
+    w1 = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                           nd_range=NDRange(dim_vec(64)), name="w1")(work))
+    w2 = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                           nd_range=NDRange(dim_vec(64)), name="w2")(work))
     data = np.arange(64, dtype=np.float32)
 
     for frac in [0.0, 0.3, 0.5, 1.0]:
@@ -199,10 +218,12 @@ def test_chunk_scheduler_straggler_and_failure(mngr, system):
 
     # flaky dies after its first failure (actor semantics) — scheduler must
     # finish all chunks on the surviving worker.
-    wf = mngr.spawn(flaky, "flaky", NDRange(dim_vec(4)),
-                    In(jnp.float32), Out(jnp.float32))
-    ws = mngr.spawn(steady, "steady", NDRange(dim_vec(4)),
-                    In(jnp.float32), Out(jnp.float32))
+    wf = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                           nd_range=NDRange(dim_vec(4)),
+                           name="flaky")(flaky))
+    ws = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                           nd_range=NDRange(dim_vec(4)),
+                           name="steady")(steady))
     sched = ChunkScheduler([wf, ws])
     payloads = [(np.full(4, i, np.float32),) for i in range(6)]
     res = sched.run(payloads, timeout=60)
@@ -236,11 +257,13 @@ def test_chunk_scheduler_speculative_reissue_beats_straggler(system):
 
 
 def test_chunk_scheduler_elastic_add_remove(mngr):
-    w1 = mngr.spawn(lambda x: x, "e1", NDRange(dim_vec(2)),
-                    In(jnp.float32), Out(jnp.float32))
+    w1 = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                           nd_range=NDRange(dim_vec(2)),
+                           name="e1")(lambda x: x))
     sched = ChunkScheduler([w1])
-    w2 = mngr.spawn(lambda x: x, "e2", NDRange(dim_vec(2)),
-                    In(jnp.float32), Out(jnp.float32))
+    w2 = mngr.spawn(kernel(In(jnp.float32), Out(jnp.float32),
+                           nd_range=NDRange(dim_vec(2)),
+                           name="e2")(lambda x: x))
     sched.add_worker(w2)
     assert len(sched.workers) == 2
     res = sched.run([(np.full(2, i, np.float32),) for i in range(4)])

@@ -242,6 +242,13 @@ def test_chain_ask_dispatches_inline(system):
     assert stats == {"inline": 3, "mailbox": 0}
 
 
+def test_fused_chain_ask_dispatches_inline(system):
+    built = _chain(system, [prep, double, sub3], name="finl").build(fuse=True)
+    x = np.arange(N, dtype=np.float32)
+    np.testing.assert_allclose(built.ask(x), (x + 1) * 2 - 3, rtol=1e-6)
+    assert built.dispatch_stats == {"inline": 1, "mailbox": 0}
+
+
 def test_request_keeps_mailbox_path(system):
     built = _chain(system, [prep, double], name="mbx").build()
     x = np.arange(N, dtype=np.float32)

@@ -303,6 +303,28 @@ def test_map_over_chunks_through_scheduler(system, ref_baseline):
     assert live_ref_count() == ref_baseline
 
 
+@kernel(In(jnp.float32), Out(jnp.float32), nd_range=NDRange(dim_vec(N)),
+        name="minus_head")
+def minus_head(x):
+    return x - x[0]
+
+
+@pytest.mark.parametrize("min_chunk_bytes,chunk_len", [(0, 16), (None, 64)],
+                         ids=["forced-4-chunks", "default-keeps-small-whole"])
+def test_map_over_min_chunk_bytes_sets_chunk_count(system, min_chunk_bytes,
+                                                   chunk_len):
+    """Each chunk sees only its own slice, so ``x - x[0]`` shows where the
+    chunks start: ``min_chunk_bytes=0`` splits 256 bytes 4 ways, the 1 MiB
+    default keeps them whole."""
+    g = Graph(system, name=f"mapchunks{chunk_len}")
+    x = g.source("x", jnp.float32)
+    kw = {} if min_chunk_bytes is None else {
+        "min_chunk_bytes": min_chunk_bytes}
+    g.output(g.map_over(minus_head, x, chunks=4, replicas=2, **kw))
+    xs = np.arange(64, dtype=np.float32)
+    np.testing.assert_allclose(g.build().ask(xs), xs % chunk_len)
+
+
 def test_map_over_rejects_multi_arg_kernels(system):
     g = Graph(system, name="mapbad")
     x = g.source("x", jnp.float32)

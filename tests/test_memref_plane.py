@@ -18,8 +18,8 @@ import pytest
 
 from repro.core import (AccessViolation, ActorPool, ActorSystem,
                         ChunkScheduler, DeviceRef, In, InOut, NDRange, Out,
-                        Pipeline, compose, dim_vec, fuse, kernel,
-                        live_ref_count, memory_stats, reset_transfer_stats,
+                        Pipeline, dim_vec, kernel, live_ref_count,
+                        memory_stats, reset_transfer_stats,
                         transfer_count)
 from repro.core.memref import registry
 

@@ -96,6 +96,19 @@ def test_wire_int8_compression_shrinks_and_bounds_error():
     assert rel <= 1 / 120
 
 
+def test_wire_int8_request_is_under_raw_host_request_over_2_5():
+    """A float32 request sent as an int8-compressed ref takes under 1/2.5
+    of the wire bytes of the same request sent raw from the host."""
+    x = np.random.RandomState(0).randn(1 << 16).astype(np.float32)
+    ref = DeviceRef.put(x)
+    try:
+        raw = wire.encoded_size((x,))
+        comp = wire.encoded_size((ref,), compress=True)
+        assert comp < raw / 2.5, (raw, comp)
+    finally:
+        ref.release()
+
+
 def test_wire_compression_skips_integer_refs():
     ref = DeviceRef.put(np.arange(16, dtype=np.int32))
     out = wire.decode(wire.encode((ref,), compress=True))

@@ -62,33 +62,6 @@ def svc(**kw):
 # ----------------------------------------------------------------------------
 # WireCostModel
 # ----------------------------------------------------------------------------
-BENCH = {"sizes": {
-    "n1024": {"local_hop_us": 310.0, "remote_hop_us": 4631.4,
-              "wire_raw_bytes": 4284, "wire_int8_bytes": 1308,
-              "compression_ratio": 3.3},
-    "n262144": {"local_hop_us": 600.0, "remote_hop_us": 14654.4,
-                "wire_raw_bytes": 1048777, "wire_int8_bytes": 262345,
-                "compression_ratio": 4.0},
-}}
-
-
-def test_wire_model_from_bench_pins_latency_and_throughput():
-    m = WireCostModel.from_bench(BENCH)
-    assert m.latency_s == pytest.approx(4631.4e-6)
-    span_s = (14654.4 - 4631.4) * 1e-6
-    assert m.bytes_per_s == pytest.approx((1048777 - 4284) / span_s)
-    assert m.int8_ratio == 4.0
-
-
-def test_wire_model_from_bench_file_and_overrides(tmp_path):
-    import json
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps(BENCH))
-    m = WireCostModel.from_bench(str(p), int8_ratio=2.0)
-    assert m.int8_ratio == 2.0
-    assert m.latency_s == pytest.approx(4631.4e-6)
-
-
 def test_wire_model_hop_and_roundtrip_prefer_int8_when_amortized():
     # slow wire, cheap compression: int8 must win when allowed
     m = WireCostModel(latency_s=1e-3, bytes_per_s=1e6, int8_ratio=4.0,
@@ -351,6 +324,23 @@ def test_place_graph_expensive_wire_stays_local():
     remote_alt = next(a for a in decisions[0].alternatives
                       if a.target == "node:b")
     assert remote_alt.cost > decisions[0].cost
+
+
+@pytest.mark.parametrize("compress", [False, "auto"], ids=["raw", "int8"])
+def test_place_graph_default_costs_keep_near_free_kernel_local(compress):
+    """Under the service's default costs a kernel on an idle device stays
+    local: neither a raw nor an int8 round trip beats it, and the
+    rejected hop is still in the audit record."""
+    site = GraphSite(idx=0, path="g/scale", in_bytes=1 << 18,
+                     out_bytes=1 << 18, remote_ok=True)
+    placements, decisions = svc().place_graph(
+        [site], [FakeDev("d0")],
+        remotes=[NodeTarget(FakeNode(compress=compress), "b")])
+    assert placements[0].name == "d0"
+    assert decisions[0].reason in ("least-loaded", "inherit-upstream")
+    rejected = next(a for a in decisions[0].alternatives
+                    if a.target == "node:b")
+    assert rejected.cost > decisions[0].cost
 
 
 def test_place_graph_int8_amortization_decides_the_hop():

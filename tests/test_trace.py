@@ -1,4 +1,4 @@
-"""Program spans (``repro.trace.span``) in a profiler trace.
+"""The program's spans (``repro.trace.span``) in a profiler trace.
 
 One tiny engine run (a toy decode step, gang scheduling, two workers,
 three requests) and one kernel-actor ask are recorded with
@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from repro.core import ActorSystem, In, NDRange, Out, dim_vec
+from repro.core import ActorSystem, In, NDRange, Out, dim_vec, kernel
 from repro.serve import ServeEngine
 
 PROMPTS = (3, 5, 7)
@@ -68,9 +68,10 @@ def run_engine_and_ask():
         engine.start()
         results = [f.result(timeout=120) for f in futs]
         engine.stop()
-        doubler = system.opencl_manager().spawn(
-            lambda x: x * 2.0, "double", NDRange(dim_vec(8)),
-            In(jnp.float32), Out(jnp.float32))
+        doubler = system.spawn(
+            kernel(In(jnp.float32), Out(jnp.float32),
+                   nd_range=NDRange(dim_vec(8)),
+                   name="double")(lambda x: x * 2.0))
         answer = doubler.ask(np.arange(8, dtype=np.float32))
         return results, engine.stats(), doubler.actor_id, answer
     finally:
